@@ -106,6 +106,9 @@ def main(argv=None) -> None:
                     help="where BENCH_<suite>.json files land "
                          "(default: repo root)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
         # every suite is expected to have a committed baseline at the repo

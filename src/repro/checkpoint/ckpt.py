@@ -317,22 +317,9 @@ class CheckpointManager:
         # build_serving_handle code path), so load_for_serving can
         # deserialize them straight into handles
         if serve_jobs:
-            # per-job shard width, mirroring assign_weight_modes: fused tile
-            # streams shard only when the tile-block count divides (pad
-            # blocks would corrupt the kernel's flat tile order), stream
-            # bundles always take the manager's width
-            cts = [None] * len(serve_jobs)
-            by_shards: dict = {}
-            for j_ix, job in enumerate(serve_jobs):
-                job_shards = (rt_streaming.fused_shards(
-                    job["k"], job["n"], self.serving_shards)
-                    if job["kind"] == "fused" else self.serving_shards)
-                by_shards.setdefault(job_shards, []).append(j_ix)
-            for job_shards, idxs in sorted(by_shards.items()):
-                group = self.codec.compress_stacked_many(
-                    [serve_jobs[j]["arr"] for j in idxs], shards=job_shards)
-                for j, ct in zip(idxs, group):
-                    cts[j] = ct
+            # the policy's own batching and per-job shard widths
+            cts = rt_streaming.compress_serving_jobs(
+                serve_jobs, self.serving_shards, self.codec)
             for job, ct in zip(serve_jobs, cts):
                 i = job["slot"]
                 handle = rt_streaming.build_serving_handle(job, ct)

@@ -44,12 +44,16 @@ def _fold_plan(a: int, n: int):
     return width, length
 
 
-def _halving_pack(vals, a: int, xp=jnp):
+def _halving_pack(vals, a: int, xp=jnp, lane_dtype=None):
     """vals: (..., N) uint16 lanes each < 2**a, 1 <= a < 8, N power of two.
 
     Returns a list of uint8 byte-plane arrays (concatenated by the caller).
+    ``lane_dtype`` (int32 inside the TPU kernels, whose VPU has no 16-bit
+    shifts) replaces both the uint16 working lanes and the uint8 planes;
+    the planes then hold one byte value per int32 lane.
     """
     assert 1 <= a < 8
+    work, out = lane_dtype or jnp.uint16, lane_dtype or jnp.uint8
     n = vals.shape[-1]
     width, length = a, n
     while width < 8 and length > 1:
@@ -58,28 +62,30 @@ def _halving_pack(vals, a: int, xp=jnp):
         width *= 2
         length = half
     if width < 8:  # degenerate tiny input: single partial byte
-        return [vals.astype(jnp.uint8)]
-    emitted = (vals & 0xFF).astype(jnp.uint8)
+        return [vals.astype(out)]
+    emitted = (vals & 0xFF).astype(out)
     residual_width = width - 8
     if residual_width == 0:
         return [emitted]
-    residual = (vals >> 8).astype(jnp.uint16)
-    return [emitted] + _halving_pack(residual, residual_width, xp)
+    residual = (vals >> 8).astype(work)
+    return [emitted] + _halving_pack(residual, residual_width, xp, lane_dtype)
 
 
-def _halving_unpack(stream, offset: int, a: int, n: int, xp=jnp):
-    """Inverse of :func:`_halving_pack`. Returns (vals (..., N) uint16, offset)."""
+def _halving_unpack(stream, offset: int, a: int, n: int, xp=jnp,
+                    work=jnp.uint16):
+    """Inverse of :func:`_halving_pack`. Returns (vals (..., N) ``work``,
+    offset)."""
     width, length = _fold_plan(a, n)
     if width < 8:
-        vals = stream[..., offset : offset + 1].astype(jnp.uint16)
+        vals = stream[..., offset : offset + 1].astype(work)
         offset += 1
     else:
-        emitted = stream[..., offset : offset + length].astype(jnp.uint16)
+        emitted = stream[..., offset : offset + length].astype(work)
         offset += length
         residual_width = width - 8
         if residual_width:
             residual, offset = _halving_unpack(stream, offset, residual_width,
-                                               length, xp)
+                                               length, xp, work)
             vals = emitted | (residual << 8)
         else:
             vals = emitted
@@ -118,27 +124,31 @@ def packed_nbytes(n: int, width: int) -> int:
     return total
 
 
-def pack_fixed(vals, width: int, xp=jnp):
+def pack_fixed(vals, width: int, xp=jnp, lane_dtype=None):
     """Pack (..., N) unsigned lanes of ``width`` significant bits into uint8.
 
     N must be a power of two (pad upstream).  Output shape:
     (..., packed_nbytes(N, width)).  ``xp=numpy`` runs the identical layout
-    on the host (wire/checkpoint path).
+    on the host (wire/checkpoint path).  ``lane_dtype=jnp.int32`` computes
+    in int32 lanes and returns the bytes as int32 values (the TPU kernels
+    narrow to uint8 only at the store).
     """
     vals = xp.asarray(vals)
     n = vals.shape[-1]
     assert n & (n - 1) == 0, f"lane count must be a power of two, got {n}"
+    out = lane_dtype or jnp.uint8
     if width == 0:
-        return xp.zeros(vals.shape[:-1] + (0,), jnp.uint8)
+        return xp.zeros(vals.shape[:-1] + (0,), out)
     planes = []
     w = width
     while w >= 8:
-        planes.append((vals & _mask(8, vals.dtype, xp)).astype(jnp.uint8))
+        planes.append((vals & _mask(8, vals.dtype, xp)).astype(out))
         vals = vals >> 8
         w -= 8
     if w:
-        sub = (vals & _mask(w, vals.dtype, xp)).astype(jnp.uint16)
-        planes.extend(_halving_pack(sub, w, xp))
+        sub = (vals & _mask(w, vals.dtype, xp)).astype(lane_dtype
+                                                         or jnp.uint16)
+        planes.extend(_halving_pack(sub, w, xp, lane_dtype))
     return xp.concatenate(planes, axis=-1)
 
 
@@ -146,8 +156,13 @@ def unpack_fixed(stream, n: int, width: int, out_dtype=jnp.uint16, xp=jnp):
     """Inverse of :func:`pack_fixed`.
 
     stream: (..., packed_nbytes(n, width)) uint8 -> (..., n) ``out_dtype``.
+    With ``out_dtype=jnp.int32`` the stream may already hold its bytes as
+    int32 lanes and every intermediate stays int32 (the TPU kernels' form).
     """
-    stream = xp.asarray(stream, jnp.uint8)
+    lanes32 = jnp.dtype(out_dtype) == jnp.int32
+    stream = xp.asarray(stream)
+    if not (lanes32 and stream.dtype == jnp.int32):
+        stream = stream.astype(jnp.uint8)
     if width == 0:
         return xp.zeros(stream.shape[:-1] + (n,), out_dtype)
     vals = xp.zeros(stream.shape[:-1] + (n,), out_dtype)
@@ -161,7 +176,8 @@ def unpack_fixed(stream, n: int, width: int, out_dtype=jnp.uint16, xp=jnp):
         shift += 8
         w -= 8
     if w:
-        sub, offset = _halving_unpack(stream, offset, w, n, xp)
+        sub, offset = _halving_unpack(stream, offset, w, n, xp,
+                                      jnp.int32 if lanes32 else jnp.uint16)
         vals = vals | (sub.astype(out_dtype) << shift)
     return vals
 

@@ -130,6 +130,45 @@ def decode_blocks(streams: BlockStreams, n_elems: int, fmt: FloatFormat,
     return combine_fields(exp.astype(fmt.uint_dtype), raw, fmt)
 
 
+def reshape_rows(a, lead):
+    """Reshape a stream array ``(..., w)`` to ``lead + (w,)``: only the
+    leading (block) dims change.
+
+    On a TPU a uint8 array is tiled 32 rows deep, so splitting or merging
+    its row dims at a count that is not a multiple of 32 is a relayout.
+    XLA compiles that copy to code proportional to the array: about 20 s
+    and 13 MB of code for one stream of StableLM-3B's embedding
+    (ahead-of-time compile for a v5e).  Viewed as uint32 words the same
+    bytes reshape in well under a second of compiling, but the byte <->
+    word views cost shift-and-or passes over the array when they run (the
+    compiler's estimate for decoding the embedding grew eightfold).  So a
+    concrete array — set-up code, run once per reshape — takes the word
+    route, and a traced one — a step program, compiled once and run every
+    step — reshapes directly.  Bit-exact either way; numpy arrays reshape
+    directly."""
+    lead = tuple(int(d) for d in lead)
+    w = a.shape[-1]
+    if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer) \
+            and a.dtype == jnp.uint8 and w % 4 == 0 and w > 0 \
+            and a.shape[:-1] != lead:
+        words = jax.lax.bitcast_convert_type(
+            a.reshape(a.shape[:-1] + (w // 4, 4)), jnp.uint32)
+        words = words.reshape(lead + (w // 4,))
+        return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(
+            lead + (w,))
+    return a.reshape(lead + (w,))
+
+
+def reshape_blocks(s: BlockStreams, lead) -> BlockStreams:
+    """Every stream of ``s`` with its block dims reshaped to ``lead``
+    (:func:`reshape_rows`; ``high_len`` has no width dim)."""
+    return BlockStreams(
+        mask=reshape_rows(s.mask, lead), low=reshape_rows(s.low, lead),
+        high=reshape_rows(s.high, lead),
+        high_len=s.high_len.reshape(tuple(lead)),
+        raw=reshape_rows(s.raw, lead))
+
+
 def flatten_blocks(s: BlockStreams) -> BlockStreams:
     """Collapse every leading ``(L, [shards,] B)`` stream layout to one
     flat block axis — the layout the per-block decoder consumes.  The
@@ -140,12 +179,7 @@ def flatten_blocks(s: BlockStreams) -> BlockStreams:
     nblocks = 1
     for d in s.mask.shape[:-1]:
         nblocks *= int(d)
-    return BlockStreams(
-        mask=s.mask.reshape(nblocks, s.mask.shape[-1]),
-        low=s.low.reshape(nblocks, s.low.shape[-1]),
-        high=s.high.reshape(nblocks, s.high.shape[-1]),
-        high_len=s.high_len.reshape(nblocks),
-        raw=s.raw.reshape(nblocks, s.raw.shape[-1]))
+    return reshape_blocks(s, (nblocks,))
 
 
 # ---------------------------------------------------------------------------
@@ -174,9 +208,9 @@ def bits_to_blocks(flat_bits, block_elems: int = DEFAULT_BLOCK_ELEMS,
     return stacked_blocks(flat_bits[None, :], block_elems, shards, pad_value)
 
 
-def stacked_blocks(bits2d, block_elems: int = DEFAULT_BLOCK_ELEMS,
+def stacked_blocks(bits, block_elems: int = DEFAULT_BLOCK_ELEMS,
                    shards: int = 1, pad_value: int = 0):
-    """(L, per) uint bit view of a layer stack -> ((L*Bs, N) blocks, Bs).
+    """(L, ...) uint bit view of a layer stack -> ((L*Bs, N) blocks, Bs).
 
     Row ``l*Bs + b`` equals block ``b`` of layer ``l``, each layer padded to
     the block size and (when ``shards > 1``) to a block count divisible by
@@ -186,21 +220,38 @@ def stacked_blocks(bits2d, block_elems: int = DEFAULT_BLOCK_ELEMS,
     zeros would make every padded group anomalous (exponent 0 is far from
     ``b``) and charge full high-stream bits for data that decode slices
     away.  Device-only: the input is never copied to the host.
+
+    The layer's trailing dims may be kept (``(L, K, N)``) or flat
+    (``(L, K*N)``).  An unpadded stack is cut into blocks by reshaping to
+    ``(L, Bs, N)`` and then merging the two leading dims, two separate
+    eager reshapes: on a TPU, the one-step ``(L, K*N) -> (L*Bs, N)``
+    reshape of a full-width weight compiles to about 100 MB of relayout
+    code in minutes, the two steps in under a second (ahead-of-time
+    compile for a v5e).
     """
-    n_layers, per = bits2d.shape
+    n_layers = bits.shape[0]
+    per = 1
+    for d in bits.shape[1:]:
+        per *= int(d)
     nblocks = (per + block_elems - 1) // block_elems
     if shards > 1:
         nblocks += (-nblocks) % shards
     total_pad = nblocks * block_elems - per
     if total_pad:
-        bits2d = jnp.pad(bits2d, ((0, 0), (0, total_pad)),
-                         constant_values=pad_value)
-    return bits2d.reshape(n_layers * nblocks, block_elems), nblocks
+        bits = jnp.pad(bits.reshape(n_layers, per), ((0, 0), (0, total_pad)),
+                       constant_values=pad_value)
+    blocks = bits.reshape(n_layers, nblocks, block_elems)
+    return blocks.reshape(n_layers * nblocks, block_elems), nblocks
 
 
 def from_blocks(bits, shape, fmt: FloatFormat):
+    """(B, N) decoded bits -> the float array of ``shape``.  Without block
+    padding the bits reshape straight to ``shape`` (a flat 1-D
+    intermediate is a slow relayout on a TPU, see :func:`stacked_blocks`)."""
     size = 1
     for s in shape:
         size *= s
+    if bits.size == size:
+        return bits.view(fmt.float_dtype).reshape(shape)
     flat = bits.reshape(-1).view(fmt.float_dtype)[:size]
     return flat.reshape(shape)

@@ -226,10 +226,17 @@ def _stack_dim(ct: CompressedTensor) -> Optional[int]:
 
 
 def _stacked_from_bits(ct: CompressedTensor, n_layers: int, bits):
-    """(L*B, N) decoded bits -> the dense ``(L,) + ct.shape`` stack."""
+    """(L*B, N) decoded bits -> the dense ``(L,) + ct.shape`` stack.
+
+    The bits are first split per layer as ``(L, B, N)`` (major dims only):
+    on a TPU the one-step ``(L*B, N) -> (L, B*N)`` reshape of a full-width
+    stack compiles to minutes of relayout code, the two eager steps in
+    under a second (see ``codec.stacked_blocks``)."""
     per = int(np.prod(ct.shape))
-    flat_layers = bits.reshape(n_layers, -1)[:, :per]
-    return flat_layers.view(ct.fmt.float_dtype).reshape(
+    bits = bits.reshape(n_layers, -1, bits.shape[-1])
+    if bits.shape[1] * bits.shape[2] != per:   # block padding to slice away
+        bits = bits.reshape(n_layers, -1)[:, :per]
+    return bits.view(ct.fmt.float_dtype).reshape(
         (n_layers,) + ct.shape).astype(jnp.dtype(ct.dtype_str))
 
 
@@ -499,6 +506,9 @@ class Codec:
         ``exact=True`` decodes the true block count without bucket
         rounding (zero pad waste; see :meth:`plan_decode`)."""
         nblocks = streams.mask.shape[0]
+        # inside a traced program the enclosing jit compiles once per shape
+        # anyway, so padding to a shared bucket would only copy the streams
+        exact = exact or isinstance(streams.mask, jax.core.Tracer)
         bucket = nblocks if exact else self._block_bucket(nblocks)
         if self.config.decode_backend != "pallas":
             if b_vec is None:
@@ -558,14 +568,16 @@ class Codec:
                 fallbacks[slot] = ("dense", None)
                 continue
             fmt = format_for(xs.dtype)
-            bits2d = xs.reshape(xs.shape[0], -1).view(fmt.uint_dtype)
-            prepared.append((slot, fmt, bits2d, xs.shape[1:], str(xs.dtype),
-                             stats_mod.stack_stats_device(bits2d, fmt)))
+            # the (L, ...) bit view keeps the layer's own dims: staging
+            # blocks from it avoids a slow TPU relayout (stacked_blocks)
+            bits = xs.view(fmt.uint_dtype)
+            prepared.append((slot, fmt, bits, xs.shape[1:], str(xs.dtype),
+                             stats_mod.stack_stats_device(bits, fmt)))
         host_stats = stats_mod.fetch_stats([pr[-1] for pr in prepared])
 
         # host search + block layout, grouped by encoder key
         groups: Dict[tuple, list] = {}
-        for (slot, fmt, bits2d, layer_shape, dtype_str, _), st in zip(
+        for (slot, fmt, bits, layer_shape, dtype_str, _), st in zip(
                 prepared, host_stats):
             if st.is_const.any():
                 # parity with the per-leaf const escape: a constant layer
@@ -579,14 +591,14 @@ class Codec:
             # params and sampled histograms
             pi = params_mod.widen_for_range(pi, *st.bounds())
             blocks, per_layer_blocks = block_codec.stacked_blocks(
-                bits2d, block_elems, shards,
+                bits, block_elems, shards,
                 pad_value=pi.b << fmt.mant_bits)
             key = self._encoder_key(fmt.name, pi, block_elems)
             groups.setdefault(key, []).append(dict(
                 slot=slot, fmt=fmt, p=pi, blocks=blocks,
-                n_layers=bits2d.shape[0], layer_shape=layer_shape,
+                n_layers=bits.shape[0], layer_shape=layer_shape,
                 dtype_str=dtype_str, per_layer_blocks=per_layer_blocks,
-                raw_bytes=bits2d.size * jnp.dtype(dtype_str).itemsize))
+                raw_bytes=bits.size * jnp.dtype(dtype_str).itemsize))
 
         buckets = []
         for key, members in groups.items():
@@ -699,7 +711,7 @@ class Codec:
                 n_layers, plb = m["n_layers"], m["per_layer_blocks"]
                 lead = ((n_layers, shards, plb // shards) if shards > 1
                         else (n_layers, plb))
-                s = jax.tree.map(lambda a: a.reshape(lead + a.shape[1:]), s)
+                s = block_codec.reshape_blocks(s, lead)
                 results[m["slot"]] = CompressedTensor(
                     streams=s, raw_bytes=None, fmt_name=m["fmt"].name,
                     params=m["p"], shape=tuple(m["layer_shape"]),
@@ -829,10 +841,8 @@ class Codec:
                                              pad_value=p.b << fmt.mant_bits)
         streams = self._encode_bucketed(bits, fmt, p, block_elems)
         if shards > 1:
-            streams = jax.tree.map(
-                lambda a: a.reshape((shards, a.shape[0] // shards)
-                                    + a.shape[1:]),
-                streams)
+            streams = block_codec.reshape_blocks(
+                streams, (shards, streams.mask.shape[0] // shards))
         ct = CompressedTensor(
             streams=streams, raw_bytes=None, fmt_name=fmt.name, params=p,
             shape=tuple(x.shape), dtype_str=str(x.dtype),

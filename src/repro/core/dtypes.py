@@ -75,19 +75,23 @@ def from_bits(bits, fmt: FloatFormat):
     return jnp.asarray(bits, fmt.uint_dtype).view(fmt.float_dtype)
 
 
-def split_fields(bits, fmt: FloatFormat):
-    """bits -> (exponent, raw) where raw = sign<<mant_bits | mantissa."""
-    bits = jnp.asarray(bits, fmt.uint_dtype)
+def split_fields(bits, fmt: FloatFormat, dtype=None):
+    """bits -> (exponent, raw) where raw = sign<<mant_bits | mantissa.
+
+    ``dtype`` overrides the lane type (the TPU kernels pass int32: the sign
+    bit is masked after the shift, so a 32-bit pattern held in int32 splits
+    exactly too)."""
+    bits = jnp.asarray(bits, dtype or fmt.uint_dtype)
     exp = (bits >> fmt.mant_bits) & fmt.exp_mask
-    sign = bits >> (fmt.total_bits - 1)
+    sign = (bits >> (fmt.total_bits - 1)) & 1
     raw = (bits & fmt.mant_mask) | (sign << fmt.mant_bits)
     return exp, raw
 
 
-def combine_fields(exp, raw, fmt: FloatFormat):
+def combine_fields(exp, raw, fmt: FloatFormat, dtype=None):
     """Inverse of :func:`split_fields`."""
-    exp = jnp.asarray(exp, fmt.uint_dtype)
-    raw = jnp.asarray(raw, fmt.uint_dtype)
+    exp = jnp.asarray(exp, dtype or fmt.uint_dtype)
+    raw = jnp.asarray(raw, dtype or fmt.uint_dtype)
     sign = raw >> fmt.mant_bits
     mant = raw & fmt.mant_mask
     return (sign << (fmt.total_bits - 1)) | (exp << fmt.mant_bits) | mant

@@ -58,7 +58,10 @@ class StackStats:
 def _stats_fn(fmt_name: str):
     fmt = FORMATS[fmt_name]
 
-    def f(bits2d):
+    def f(bits):
+        # (L, ...) in: the flat (L, N) view is taken here, inside the jit,
+        # where it feeds reductions and is never materialized
+        bits2d = bits.reshape(bits.shape[0], -1)
         exp = (bits2d >> fmt.mant_bits) & jnp.asarray(fmt.exp_mask,
                                                       bits2d.dtype)
         flat = exp.reshape(-1)
@@ -93,8 +96,8 @@ def exponent_histogram_device(x, fmt: FloatFormat) -> jax.Array:
 
 
 def stack_stats_device(bits2d, fmt: FloatFormat):
-    """(hist, lo, hi, is_const, first) as device arrays for a ``(L, N)`` bit
-    view.  One fused jit dispatch; pair with :func:`fetch_stats` to batch the
+    """(hist, lo, hi, is_const, first) as device arrays for an ``(L, ...)``
+    bit view (per layer: its flattened elements).  One fused jit dispatch; pair with :func:`fetch_stats` to batch the
     host transfer across many stacks."""
     return _stats_fn(fmt.name)(bits2d)
 

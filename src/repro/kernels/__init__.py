@@ -1,4 +1,6 @@
-"""Pallas TPU kernels for ENEC hot spots (validated via interpret=True).
+"""Pallas TPU kernels for ENEC hot spots: native on a TPU, interpret mode
+elsewhere (checked bit-exact there; tests/test_tpu_compile.py compiles them
+for a described v5e).
 
 enec_encode / enec_decode : the block codec (paper §IV-B + §V)
 idd_scan                  : prefix sum, MXU triangular-matmul adaptation (§V-D)

@@ -1,20 +1,21 @@
 """ENEC block decompression as a Pallas TPU kernel.
 
-Up to ``_STEP_ELEM_BUDGET`` block elements per grid step (multiple blocks
-for small block sizes — amortizes grid overhead on small tensors); every
-stream tile lives in VMEM (mask 128 B + low N·m/8 + high N·(n-m)/8 +
-raw N·r/8 ≈ 30 KB for BF16 at (n=6, m=3) — comfortably double-buffered by
-Pallas against the ~16 MB VMEM).
+Each grid step decodes ``lanes.ROWS`` blocks, one per sublane row: the
+stream tiles arrive as ``(8, nbytes)`` slabs of the flat ``(B, nbytes)``
+uint8 streams (no relayout in HBM; the last step may be partial and its
+extra rows are never written back), and every intermediate is an
+``(8, N)`` int32 array on the block's flat element order.
 
-TPU adaptations inside the body (DESIGN.md §2):
-  * prefix sum over the anomaly mask  -> IDD-Scan (MXU triangular matmul)
-  * reverse gather of anomalous high bits -> segment-local one-hot MXU
-    matmul: destination segment s only ever reads the 128 rank-ordered rows
-    starting at its exclusive anomaly offset (the IDD-scan's stage-2 row
-    offset), so each segment is one (128, 128) one-hot matmul — O(G·128·L)
-    MXU FLOPs instead of the chunked (128, G) one-hot's O(G²·L)
+TPU adaptations inside the body (see ``kernels/lanes.py``):
+  * anomaly mask -> per-lane flags: one lane broadcast per mask byte
+  * prefix count over the anomaly mask (the paper's IDD-Scan target) ->
+    log-step scan over group-strided lane shifts
+  * reverse gather of the rank-ordered high bits -> a shift network: a
+    compaction routes each anomalous group's displacement to its rank
+    slot, an expansion carries the high bits back out to the group
   * exponent inverse mapping -> branch-free linear transform (VPU add/and)
-  * bit-unpacking -> static unrolled halving un-fold (slices + shift + or)
+  * bit-unpacking -> static halving un-fold on the lane axis, in int32
+    lanes (the TPU VPU has no 16-bit shifts)
 
 The pure-jnp oracle is ``repro.core.codec.decode_blocks`` (see ref.py); the
 kernel is verified element-exact against it across shape/dtype/param sweeps
@@ -37,99 +38,50 @@ from repro.core import bitio, codec, transform
 from repro.core.dtypes import FloatFormat, combine_fields
 from repro.core.params import EnecParams
 
-from .idd_scan import scan_2d
-
-GATHER_SEG = 128
-# elements decoded per grid step: one 16,384-element block, or up to 8
-# smaller blocks unrolled in one step so tiny tensors don't pay one grid
-# step (and its stream-tile DMA round) per block
-_STEP_ELEM_BUDGET = 16384
-_MAX_BLOCKS_PER_STEP = 8
+from . import lanes
 
 
-def _mask_to_bits(mask_bytes, g: int):
-    """(Gb,) u8 -> (G,) int32 bits, little endian (matches pack_bool_mask)."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (mask_bytes.shape[0], 8), 1)
-    bits = (mask_bytes[:, None] >> shifts) & jnp.uint8(1)
-    return bits.reshape(g).astype(jnp.int32)
-
-
-def _exclusive_rank(anom_i32, g: int):
-    """Exclusive prefix sum of the anomaly bits via IDD-Scan."""
-    lane = 128 if g % 128 == 0 else g
-    mat = anom_i32.astype(jnp.float32).reshape(g // lane, lane)
-    incl = scan_2d(mat).reshape(g)
-    return incl.astype(jnp.int32) - anom_i32
-
-
-def _segment_gather(high_dense_f32, rank, anom_i32, g: int, l: int):
-    """gathered[gr] = high_dense[rank[gr]] if anom[gr] else 0 — on the MXU.
-
-    The exclusive ranks of the groups in segment ``s`` (128 destinations)
-    all lie in ``[start, start + 127]`` with ``start = rank[s * 128]`` —
-    the segment's exclusive anomaly offset, which the IDD-scan's stage-2
-    row propagation already materialized.  One dynamic 128-row slice of the
-    rank-ordered source plus one (128, 128) one-hot matmul therefore covers
-    every destination in the segment, and MXU work scales with the group
-    count instead of its square.  ``start <= s * 128`` (at most one anomaly
-    per preceding group), so the slice never runs off the end.
-    """
-    seg = min(GATHER_SEG, g)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (seg, seg), 1)
-    outs = []
-    for c in range(0, g, seg):
-        rk = jax.lax.dynamic_slice_in_dim(rank, c, seg)
-        am = jax.lax.dynamic_slice_in_dim(anom_i32, c, seg)
-        start = rk[0]
-        src = jax.lax.dynamic_slice_in_dim(high_dense_f32, start, seg)
-        onehot = (((rk - start)[:, None] == iota) &
-                  (am[:, None] > 0)).astype(jnp.float32)
-        outs.append(jax.lax.dot_general(
-            onehot, src, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
-    return jnp.concatenate(outs, axis=0)  # (G, L) f32, exact (< 2**m values)
+def decode_rows(mask, low, high, raw, *, n_elems: int, fmt: FloatFormat,
+                p: EnecParams):
+    """Decode one block per row: int32 stream rows ``(R, nbytes)`` ->
+    ``(R, n_elems)`` int32 bit patterns."""
+    L = p.L
+    y = bitio.unpack_fixed(low, n_elems, p.m, out_dtype=jnp.int32)
+    if p.n > p.m:
+        lane = lanes.lane_iota(y.shape)
+        byte = lanes.byte_per_lane(mask, L, n_elems)
+        flag = (byte >> ((lane // L) & 7)) & 1
+        rank = lanes.group_count(flag, L) - flag
+        home = lanes.compact(lanes.routed_word(flag, 0, lane // L - rank),
+                             L, n_elems // L)
+        dense = bitio.unpack_fixed(high, n_elems, p.n - p.m,
+                                   out_dtype=jnp.int32)
+        word = jnp.where((home & 1) != 0, home | (dense << 1), 0)
+        gathered = lanes.routed_payload(
+            lanes.expand(word, L, n_elems // L))
+        y = y | (gathered << p.m)
+    exp = transform.inverse(y, p.b, p.n, p.l)
+    rawv = bitio.unpack_fixed(raw, n_elems, fmt.raw_bits, out_dtype=jnp.int32)
+    return combine_fields(exp, rawv, fmt, dtype=jnp.int32)
 
 
 def decode_block_body(mask_b, low_b, high_b, raw_b, *, n_elems: int,
                       fmt: FloatFormat, p: EnecParams):
     """Decode one block. 1-D uint8 stream slices -> (n_elems,) uint bits."""
-    g = n_elems // p.L
-    anom = _mask_to_bits(mask_b, g)
-    rank = _exclusive_rank(anom, g)
-
-    y_low = bitio.unpack_fixed(low_b[None, :], n_elems, p.m)[0]
-    y = y_low
-    if p.n > p.m:
-        high_dense = bitio.unpack_fixed(high_b[None, :], n_elems, p.n - p.m)[0]
-        high_dense = high_dense.reshape(g, p.L).astype(jnp.float32)
-        gathered = _segment_gather(high_dense, rank, anom, g, p.L)
-        gathered = gathered.astype(jnp.uint16).reshape(n_elems)
-        y = y_low | (gathered << p.m)
-
-    exp = transform.inverse(y, p.b, p.n, p.l)
-    raw = bitio.unpack_fixed(raw_b[None, :], n_elems, fmt.raw_bits,
-                             out_dtype=fmt.uint_dtype)[0]
-    return combine_fields(exp.astype(fmt.uint_dtype), raw, fmt)
+    rows = [s[None, :].astype(jnp.int32) for s in (mask_b, low_b, high_b,
+                                                    raw_b)]
+    bits = decode_rows(*rows, n_elems=n_elems, fmt=fmt, p=p)[0]
+    return lanes.to_uint(bits, fmt.uint_dtype)
 
 
 def _decode_kernel(mask_ref, low_ref, high_ref, raw_ref, out_ref, *,
-                   n_elems, fmt, p, block_step):
-    for j in range(block_step):
-        out_ref[j] = decode_block_body(
-            mask_ref[j], low_ref[j], high_ref[j], raw_ref[j],
-            n_elems=n_elems, fmt=fmt, p=p)
-
-
-def blocks_per_step(nblocks: int, n_elems: int) -> int:
-    """Largest power-of-two block count per grid step that divides the
-    total, stays within the per-step element budget, and bounds the body
-    unroll — the batched pipeline's bucketed counts (pow2 / 64-multiples)
-    always divide cleanly."""
-    bs = 1
-    while (bs * 2 <= _MAX_BLOCKS_PER_STEP and nblocks % (bs * 2) == 0
-           and bs * 2 * n_elems <= _STEP_ELEM_BUDGET):
-        bs *= 2
-    return bs
+                   n_elems, fmt, p):
+    bits = decode_rows(mask_ref[...].astype(jnp.int32),
+                       low_ref[...].astype(jnp.int32),
+                       high_ref[...].astype(jnp.int32),
+                       raw_ref[...].astype(jnp.int32),
+                       n_elems=n_elems, fmt=fmt, p=p)
+    out_ref[...] = lanes.to_uint(bits, out_ref.dtype)
 
 
 def decode_blocks_pallas(streams: codec.BlockStreams, n_elems: int,
@@ -147,23 +99,23 @@ def decode_blocks_pallas(streams: codec.BlockStreams, n_elems: int,
         streams = codec.flatten_blocks(streams)
     nblocks = streams.mask.shape[0]
     widths = codec.stream_shapes(n_elems, fmt, p)
-    bs = blocks_per_step(nblocks, n_elems)
+    rows = lanes.step_rows(nblocks)
 
     def spec(nbytes):
-        return pl.BlockSpec((bs, max(nbytes, 1)), lambda i: (i, 0))
+        return pl.BlockSpec((rows, max(nbytes, 1)), lambda i: (i, 0))
 
     high = streams.high
     if widths["high"] == 0:  # m == n: no high stream; feed a dummy byte
         high = jnp.zeros((nblocks, 1), jnp.uint8)
 
     fn = pl.pallas_call(
-        functools.partial(_decode_kernel, n_elems=n_elems, fmt=fmt, p=p,
-                          block_step=bs),
-        grid=(nblocks // bs,),
+        functools.partial(_decode_kernel, n_elems=n_elems, fmt=fmt, p=p),
+        grid=(pl.cdiv(nblocks, rows),),
         in_specs=[spec(widths["mask"]), spec(widths["low"]),
                   spec(widths["high"]), spec(widths["raw"])],
-        out_specs=pl.BlockSpec((bs, n_elems), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rows, n_elems), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, n_elems), fmt.uint_dtype),
         interpret=interpret,
+        name="enec_decode",
     )
     return fn(streams.mask, streams.low, high, streams.raw)

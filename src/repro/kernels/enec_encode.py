@@ -1,14 +1,16 @@
 """ENEC block compression as a Pallas TPU kernel.
 
-One block per grid step.  Mirrors ``codec.encode_blocks``:
+``lanes.ROWS`` blocks per grid step, one per sublane row (the layout of
+``enec_decode``).  Mirrors ``codec.encode_blocks``:
 
   split fields -> branch-free linear map -> group OR (replaces reduction
-  max, §V-B) -> anomaly mask -> IDD-Scan ranks -> one-hot MXU *scatter* of
-  anomalous groups' high bits into rank order -> hierarchical halving pack.
+  max, §V-B) -> anomaly mask -> prefix count of anomalous groups ->
+  compaction network moving the anomalous groups' high bits into rank
+  order -> hierarchical halving pack.
 
-The scatter is the transpose of the decode gather: S[r, g] = 1 iff group g
-is the r-th anomalous group, high_dense = S @ y_high.  Same 128-slab
-chunking keeps the one-hot tile at (128, G) f32 in VMEM.
+The compaction is the inverse of the decode expansion: every anomalous
+group moves left by the number of clean groups before it, one bit of that
+displacement per stage (``kernels/lanes.py``).
 """
 from __future__ import annotations
 
@@ -22,68 +24,41 @@ from repro.core import bitio, codec, transform
 from repro.core.dtypes import FloatFormat, split_fields
 from repro.core.params import EnecParams
 
-from .enec_decode import _exclusive_rank, _mask_to_bits  # shared helpers
-from .idd_scan import scan_2d
-
-SCATTER_CHUNK = 128
+from . import lanes
 
 
-def _onehot_scatter(y_high_f32, rank, anom_i32, g: int, l: int):
-    """high_dense[r] = y_high[g] where rank[g] == r and anom[g] — on the MXU."""
-    chunk = min(SCATTER_CHUNK, g)
-    g_iota = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-    outs = []
-    for c in range(0, g, chunk):
-        # S[r - c, g'] = (rank[g'] == r) & anom[g']
-        onehot = ((rank[None, :] == (g_iota + c)) &
-                  (anom_i32[None, :] > 0)).astype(jnp.float32)
-        outs.append(jax.lax.dot_general(
-            onehot, y_high_f32, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
-    return jnp.concatenate(outs, axis=0)  # (G, L) rank-ordered, zero padded
-
-
-def encode_block_body(bits, *, n_elems: int, fmt: FloatFormat, p: EnecParams):
-    """bits: (n_elems,) uint view -> (mask, low, high, high_len, raw) slices."""
-    g = n_elems // p.L
-    exp, raw = split_fields(bits, fmt)
-    y = transform.forward(exp.astype(jnp.uint16), p.b, p.n)
-
-    yg = y.reshape(g, p.L)
-    gor = jax.lax.reduce(yg, jnp.uint16(0), jnp.bitwise_or, (1,))
-    anom = ((gor >> p.m) != 0)
-    anom_i32 = anom.astype(jnp.int32)
-
-    mask = bitio.pack_bool_mask(anom[None, :])[0]
-    low = bitio.pack_fixed((y & jnp.uint16((1 << p.m) - 1))[None, :], p.m)[0]
-
+def encode_rows(bits, *, n_elems: int, fmt: FloatFormat, p: EnecParams):
+    """Encode one block per row: ``(R, n_elems)`` int32 bit patterns ->
+    int32 byte rows ``(mask, low, high, raw)`` and ``(R, 1)`` high_len."""
+    L = p.L
+    exp, raw = split_fields(bits, fmt, dtype=jnp.int32)
+    y = transform.forward(exp, p.b, p.n)
+    flag = (lanes.group_or(y >> p.m, L) != 0).astype(jnp.int32)
+    mask = lanes.mask_bytes(flag, L)
+    low = bitio.pack_fixed(y & ((1 << p.m) - 1), p.m, lane_dtype=jnp.int32)
     if p.n > p.m:
-        rank = _exclusive_rank(anom_i32, g)
-        y_high = (yg >> p.m).astype(jnp.float32)
-        high_dense = _onehot_scatter(y_high, rank, anom_i32, g, p.L)
-        high_dense = high_dense.astype(jnp.uint16).reshape(n_elems)
-        high = bitio.pack_fixed(high_dense[None, :], p.n - p.m)[0]
-        high_len = jnp.sum(anom_i32) * (p.L * (p.n - p.m))
+        lane = lanes.lane_iota(y.shape)
+        count = lanes.group_count(flag, L)
+        word = lanes.routed_word(flag, y >> p.m, lane // L - (count - flag))
+        dense = lanes.routed_payload(lanes.compact(word, L, n_elems // L))
+        high = bitio.pack_fixed(dense, p.n - p.m, lane_dtype=jnp.int32)
+        high_len = count[:, -1:] * (L * (p.n - p.m))
     else:
-        high = jnp.zeros((0,), jnp.uint8)
-        high_len = jnp.int32(0)
-
-    rawp = bitio.pack_fixed(raw[None, :], fmt.raw_bits)[0]
+        high = jnp.zeros((y.shape[0], 1), jnp.int32)
+        high_len = jnp.zeros((y.shape[0], 1), jnp.int32)
+    rawp = bitio.pack_fixed(raw, fmt.raw_bits, lane_dtype=jnp.int32)
     return mask, low, high, high_len, rawp
 
 
 def _encode_kernel(bits_ref, mask_ref, low_ref, high_ref, hlen_ref, raw_ref,
                    *, n_elems, fmt, p):
-    mask, low, high, high_len, rawp = encode_block_body(
-        bits_ref[0], n_elems=n_elems, fmt=fmt, p=p)
-    mask_ref[0] = mask
-    low_ref[0] = low
-    if p.n > p.m:
-        high_ref[0] = high
-    else:
-        high_ref[0] = jnp.zeros_like(high_ref[0])
-    hlen_ref[0, 0] = high_len
-    raw_ref[0] = rawp
+    mask, low, high, high_len, rawp = encode_rows(
+        lanes.to_int32(bits_ref[...]), n_elems=n_elems, fmt=fmt, p=p)
+    mask_ref[...] = mask.astype(jnp.uint8)
+    low_ref[...] = low.astype(jnp.uint8)
+    high_ref[...] = high.astype(jnp.uint8)
+    hlen_ref[...] = high_len
+    raw_ref[...] = rawp.astype(jnp.uint8)
 
 
 def encode_blocks_pallas(bits, fmt: FloatFormat, p: EnecParams, *,
@@ -91,9 +66,10 @@ def encode_blocks_pallas(bits, fmt: FloatFormat, p: EnecParams, *,
     """Pallas counterpart of ``codec.encode_blocks`` (same layout)."""
     nblocks, n_elems = bits.shape
     widths = codec.stream_shapes(n_elems, fmt, p)
+    rows = lanes.step_rows(nblocks)
 
     def spec(nbytes):
-        return pl.BlockSpec((1, max(nbytes, 1)), lambda i: (i, 0))
+        return pl.BlockSpec((rows, max(nbytes, 1)), lambda i: (i, 0))
 
     out_shape = (
         jax.ShapeDtypeStruct((nblocks, widths["mask"]), jnp.uint8),
@@ -104,13 +80,13 @@ def encode_blocks_pallas(bits, fmt: FloatFormat, p: EnecParams, *,
     )
     fn = pl.pallas_call(
         functools.partial(_encode_kernel, n_elems=n_elems, fmt=fmt, p=p),
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, n_elems), lambda i: (i, 0))],
+        grid=(pl.cdiv(nblocks, rows),),
+        in_specs=[pl.BlockSpec((rows, n_elems), lambda i: (i, 0))],
         out_specs=(spec(widths["mask"]), spec(widths["low"]),
-                   spec(widths["high"]), pl.BlockSpec((1, 1), lambda i: (i, 0)),
-                   spec(widths["raw"])),
+                   spec(widths["high"]), spec(1), spec(widths["raw"])),
         out_shape=out_shape,
         interpret=interpret,
+        name="enec_encode",
     )
     mask, low, high, hlen, raw = fn(bits)
     return codec.BlockStreams(

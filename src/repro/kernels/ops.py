@@ -20,6 +20,7 @@ from repro.core.params import EnecParams
 
 from . import ref
 from .decompress_matmul import decompress_matmul as _fused
+from .decompress_matmul import tiled_matmul as _tiled
 from .decompress_matmul import tile_weights_for_fusion  # re-export  # noqa: F401
 from .enec_decode import decode_blocks_pallas
 from .enec_encode import encode_blocks_pallas
@@ -91,6 +92,16 @@ def pipeline_decoder(fmt: FloatFormat, p: EnecParams, n_elems: int,
     """
     return jax.jit(functools.partial(decode_blocks, n_elems=n_elems,
                                      fmt=fmt, p=p, use_pallas=use_pallas))
+
+
+def tiled_matmul(x, w):
+    """The canonical serve matmul ``x (M, K) @ w (K, N) -> (M, N) f32`` of a
+    dense weight: the Pallas kernel on a TPU (where XLA's contraction
+    depends on the program around it), ``ref.tiled_matmul_ref`` elsewhere —
+    bit-identical to each other and to :func:`decompress_matmul`."""
+    if _interpret():
+        return ref.tiled_matmul_ref(x, w)
+    return _tiled(x, w, interpret=False)
 
 
 def decompress_matmul(x, ct: CompressedTensor, k: int, n: int,

@@ -30,39 +30,34 @@ def decode_blocks_ref(streams, n_elems: int, fmt: FloatFormat, p: EnecParams):
 
 def tiled_matmul_ref(x, w):
     """Canonical serve matmul: x (M, K) @ w (K, N) -> (M, N) f32 realizing
-    the fused kernel's exact schedule — 128x128 weight tiles, zero-padded
-    ragged edges, k-major f32 accumulation per output strip.
+    the fused kernel's exact schedule — the K axis cut into 128-wide
+    tiles, every (M, 128) @ (128, N) tile product computed by the kernel's
+    own :func:`~repro.kernels.decompress_matmul.tile_dot`, then summed in
+    k order (ragged edges zero padded).
 
     This is the numeric contract of the weight-execution abstraction
     (runtime/weights.py): every mode's ``matmul`` is either this function on
     a materialized weight or the Pallas kernel on compressed tiles, and the
     two are bit-identical by construction (same dot shapes, same values,
     same accumulation order) — which is what makes dense / stream / fused
-    serve logits bit-identical.
+    serve logits bit-identical.  The tile products are one batched dot, so
+    the program stays small at any width.
     """
-    from .decompress_matmul import TILE
+    from .decompress_matmul import TILE, tile_dot
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
     kp, np_ = -(-k // TILE) * TILE, -(-n // TILE) * TILE
-    xf = x.astype(jnp.float32)
-    wf = w.astype(jnp.float32)
     if kp != k:
-        xf = jnp.pad(xf, ((0, 0), (0, kp - k)))
+        x = jnp.pad(x, ((0, 0), (0, kp - k)))
     if (kp, np_) != (k, n):
-        wf = jnp.pad(wf, ((0, kp - k), (0, np_ - n)))
-    strips = []
-    for ni in range(np_ // TILE):
-        acc = None
-        for ki in range(kp // TILE):
-            part = jax.lax.dot_general(  # the exact dot the kernel issues
-                xf[:, ki * TILE:(ki + 1) * TILE],
-                wf[ki * TILE:(ki + 1) * TILE, ni * TILE:(ni + 1) * TILE],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            acc = part if acc is None else acc + part
-        strips.append(acc)
-    out = jnp.concatenate(strips, axis=1)
+        w = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
+    kt = kp // TILE
+    parts = tile_dot(x.reshape(m, kt, TILE).transpose(1, 0, 2),
+                     w.reshape(kt, TILE, np_))          # (kt, M, N)
+    out = parts[0]
+    for i in range(1, kt):                             # the kernel's k order
+        out = out + parts[i]
     return out[:, :n] if np_ != n else out
 
 

@@ -6,18 +6,27 @@ state (the dry-run must set XLA_FLAGS before the first jax call).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules of this repo
+    (``runtime/sharding.py``) are annotations the compiler propagates, not
+    explicit-sharding types (newer JAX defaults every axis to Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (elastic re-mesh / tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _mesh(shape, axes)
 
 
 def largest_model_axis(n: int, cap=None) -> int:
@@ -44,11 +53,11 @@ def make_host_mesh(*, model=None, max_model=None):
     """
     n = len(jax.devices())
     if model is None and max_model is None:
-        return jax.make_mesh((n,), ("data",))
+        return _mesh((n,), ("data",))
     if model in (None, "max"):
         model = largest_model_axis(n, max_model)
     model = int(model)
     if model < 1 or n % model:
         raise ValueError(
             f"model axis {model} does not divide the {n} local devices")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))

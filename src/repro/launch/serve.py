@@ -31,6 +31,15 @@ can produce and consume its own checkpoint.
     PYTHONPATH=src python -m repro.launch.serve --arch llama3_2_1b --smoke \
         --batch 4 --tokens 8 --mode fused --ckpt /tmp/ck
 
+Programmatic use: ``main(argv)`` takes the CLI's arguments and returns a
+:class:`ServeResult` (the requests with their states, tokens and, with
+``--collect-logits``, logits), so callers such as ``chip_smoke.py`` check
+what was served through this exact code path.  ``--warmup`` serves the
+batch once untimed first; ``--verify-codec`` decodes every compressed leaf
+and compares it to the initialised weights.  The codec runs the Pallas
+kernels on a TPU and the jnp reference elsewhere unless
+``--codec-backend`` says otherwise.
+
 Reliability (docs/RELIABILITY.md): restores run with record quarantine and
 per-record fallback.  ``--degraded`` (default) serves with the fallback
 handles and prints the RestoreReport; ``--strict`` exits nonzero with the
@@ -46,8 +55,10 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
@@ -124,10 +135,59 @@ def _restore_params(args, model, mode, codec, policy, mesh=None,
     return params, report
 
 
-def main():
+@dataclasses.dataclass
+class ServeResult:
+    """What one :func:`main` run served: the requests (state, tokens and,
+    with ``--collect-logits``, per-token logits), the engine that served
+    them (kept so a caller can inspect its compiled step), and the
+    launcher's measurements."""
+    requests: list
+    engine: Engine
+    stats: dict               # engine.stats()["engine"]
+    wall_s: float             # the served batch, warm-up excluded
+    tok_s: float
+    warmup_s: Optional[float]  # --warmup: the untimed, compiling pass
+    weight_stats: dict        # stream_stats(params)
+    verify: Optional[dict]    # --verify-codec: leaves checked / mismatched
+
+    def all_done(self) -> bool:
+        return all(r.state == "done" for r in self.requests)
+
+
+def _verify_codec(dense, tree, codec) -> dict:
+    """Decode every compressed leaf of ``tree`` with ``codec`` and compare
+    its bits to the leaf of ``dense`` it was built from.  One leaf at a
+    time, so the check holds at most one extra dense leaf on the device."""
+    from repro.runtime.weights import (FusedWeight, StreamedWeight,
+                                       is_handle, materialize_full)
+
+    def bits(a):
+        return jax.lax.bitcast_convert_type(
+            a, {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize])
+
+    # one fused comparison: no bit-view copies of two full leaves
+    same_bits = jax.jit(lambda a, b: jnp.array_equal(bits(a), bits(b)))
+    checked = mismatched = 0
+    for orig, leaf in zip(jax.tree.leaves(dense),
+                          jax.tree.leaves(tree, is_leaf=is_handle)):
+        if not isinstance(leaf, (StreamedWeight, FusedWeight)):
+            continue
+        got = materialize_full(leaf, codec)
+        same = got.shape == orig.shape and bool(same_bits(got, orig))
+        checked += 1
+        mismatched += not same
+        del got
+    return {"leaves": checked, "mismatched": mismatched}
+
+
+def main(argv=None) -> ServeResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3_2_1b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve only the first N layers of --arch, at its "
+                         "full width (a model cut to fit a time or memory "
+                         "budget)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=8)
@@ -156,7 +216,7 @@ def main():
     ap.add_argument("--shards", type=int, default=None,
                     help="stream-mode TP shard count for the block dim "
                          "(default: the serving mesh's model-axis width "
-                         "under --tp/--mesh, else 2)")
+                         "under --tp/--mesh, else 1)")
     ap.add_argument("--tp", type=int, default=1,
                     help="model-axis width of the serving mesh "
                          "(docs/DISTRIBUTED.md): stream shards live "
@@ -167,10 +227,21 @@ def main():
                     help="build a (data, model) serving mesh with the "
                          "largest model axis the local device count "
                          "divides by (shorthand for --tp <max divisor>)")
-    ap.add_argument("--codec-backend", default="reference",
+    ap.add_argument("--codec-backend", default=None,
                     choices=("reference", "pallas"),
                     help="encode/decode backend of the launcher's Codec "
-                         "instance (docs/API.md)")
+                         "instance (docs/API.md); default pallas on a TPU, "
+                         "reference elsewhere")
+    ap.add_argument("--warmup", action="store_true",
+                    help="serve the same batch once untimed first, so "
+                         "every prefill/decode shape is compiled before "
+                         "the timed run")
+    ap.add_argument("--collect-logits", action="store_true",
+                    help="keep every request's per-token logits on the "
+                         "returned requests")
+    ap.add_argument("--verify-codec", action="store_true",
+                    help="decode every compressed leaf and check it is "
+                         "bit-identical to the weights it was built from")
     ap.add_argument("--ckpt", default=None, metavar="DIR",
                     help="restore weights from an ENEC checkpoint via "
                          "load_for_serving (docs/CHECKPOINT.md)")
@@ -197,18 +268,22 @@ def main():
     pol.add_argument("--degraded", action="store_true",
                      help="serve through damage with per-record fallbacks "
                           "and print the RestoreReport (default)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.dense and args.mode not in (None, "dense"):
         ap.error("--dense conflicts with --mode " + args.mode)
     if args.ckpt and args.save_ckpt:
         ap.error("--ckpt and --save-ckpt are mutually exclusive "
                  "(restored weights are already checkpointed)")
+    if args.verify_codec and args.ckpt:
+        ap.error("--verify-codec checks freshly initialised weights; a "
+                 "restore is verified by its record CRCs")
     mode = "dense" if args.dense else (args.mode or "fused")
     policy = "strict" if args.strict else "degraded"
     if args.expert_cache_mb is not None and (args.mesh or args.tp > 1):
         ap.error("--expert-cache-mb does not compose with --mesh/--tp yet: "
                  "the expert store decodes host-side per step (docs/MOE.md)")
     HEALTH.reset()   # embedded back-to-back runs never inherit stale state
+    verify = None
 
     mesh = None
     if args.mesh or args.tp > 1:
@@ -218,17 +293,21 @@ def main():
         print(f"[launch.serve] serving mesh axes {dict(mesh.shape)}")
     if args.shards is None:
         # shard width follows the mesh so the stream shards actually land
-        # one-per-device (an explicit --shards may still over/under-shard)
-        args.shards = mesh.shape["model"] if mesh is not None else 2
+        # one-per-device (an explicit --shards may still over/under-shard);
+        # one device keeps one shard, so a layer's streams need no
+        # relayout before they decode
+        args.shards = mesh.shape["model"] if mesh is not None else 1
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
-    cfg = dataclasses.replace(cfg, scan_layers=True, overlap=args.overlap)
+    cfg = dataclasses.replace(cfg, scan_layers=True, overlap=args.overlap,
+                              n_layers=args.layers or cfg.n_layers)
     model = build_model(cfg)
     # one explicit Codec instance owns this server's compression state —
     # caches, cache stats, and the h2d transfer counter are all scoped to
     # it, so a second model in the same process cannot perturb them
-    codec = Codec(encode_backend=args.codec_backend,
-                  decode_backend=args.codec_backend)
+    backend = args.codec_backend or (
+        "pallas" if jax.default_backend() == "tpu" else "reference")
+    codec = Codec(encode_backend=backend, decode_backend=backend)
     expert_store = None
     if args.expert_cache_mb is not None:
         # 0 MB is a legal budget: every routed expert is a miss and is
@@ -263,7 +342,9 @@ def main():
         else:
             HEALTH.transition("ready")
     else:
+        t_setup = time.perf_counter()
         params = model.init(jax.random.key(0))
+        dense = params if args.verify_codec else None
         if expert_store is not None:
             # BEFORE assign_weight_modes: expert stacks become ExpertRef
             # handles and the mode assignment passes them through
@@ -273,6 +354,17 @@ def main():
         params = assign_weight_modes(params, mode=mode,
                                      min_bytes=args.min_bytes,
                                      shards=args.shards, codec=codec)
+        jax.block_until_ready(jax.tree.leaves(params))
+        print(f"[launch.serve] weights initialised and assigned ({mode}) "
+              f"in {time.perf_counter() - t_setup:.2f}s")
+        if dense is not None:
+            t0 = time.perf_counter()
+            verify = _verify_codec(dense, params, codec)
+            verify["seconds"] = time.perf_counter() - t0
+            del dense
+            print(f"[launch.serve] codec round trip ({backend}): "
+                  f"{verify['leaves']} compressed leaves, "
+                  f"{verify['mismatched']} mismatched")
         if mesh is not None:
             from repro.runtime.collectives import place_serving_tree
             params = place_serving_tree(params, mesh)
@@ -294,8 +386,9 @@ def main():
         HEALTH.transition("ready")
     print(f"[launch.serve] health={HEALTH.state} ready={HEALTH.ready()} "
           f"policy={policy} mode_mix={mode_mix(params)}")
-    print(f"[launch.serve] mode={mode} overlap={args.overlap}:",
-          stream_stats(params))
+    weight_stats = stream_stats(params)
+    print(f"[launch.serve] mode={mode} overlap={args.overlap} "
+          f"codec={backend}:", weight_stats)
 
     # ---- engine-driven serving (docs/TRAFFIC.md) -------------------------
     # The Engine traces every jit dispatch under the launcher's codec (its
@@ -312,26 +405,39 @@ def main():
         max_prompt_len=args.prompt_len,
         max_new_tokens=args.tokens,
         default_deadline_s=args.deadline_ms / 1e3 if args.deadline_ms
-        else None)
+        else None,
+        collect_logits=args.collect_logits)
     engine = Engine(model, params, ecfg, codec=codec, health=HEALTH,
                     extra_context=extra_ctx, expert_store=expert_store)
     prompts = np.asarray(jax.random.randint(
         jax.random.key(1), (args.batch, args.prompt_len), 0,
         cfg.vocab_size), np.int32)
 
+    def serve_batch(tag):
+        reqs = [engine.submit(prompts[i], args.tokens, name=f"{tag}{i}")
+                for i in range(args.batch)]
+        engine.run_until_idle()
+        return reqs
+
+    warmup_s = None
+    if args.warmup:
+        t0 = time.perf_counter()
+        serve_batch("warmup")
+        warmup_s = time.perf_counter() - t0
+        engine.reset_governor()
+        print(f"[launch.serve] warm-up batch (compiles included) "
+              f"{warmup_s:.2f}s")
     t0 = time.perf_counter()
-    reqs = [engine.submit(prompts[i], args.tokens, name=f"seq{i}")
-            for i in range(args.batch)]
-    engine.run_until_idle()
+    reqs = serve_batch("seq")
     wall = time.perf_counter() - t0
 
     finished = [r for r in reqs if r.state in ("done", "timed_out")]
+    n_tok = sum(len(r.tokens) for r in finished)
     ttfts = [r.ttft_s() for r in finished if r.ttft_s() is not None]
     ttft = sum(ttfts) / len(ttfts) if ttfts else 0.0
     if args.tokens > 1:
         tpots = [r.tpot_s() for r in finished if r.tpot_s() is not None]
         tpot = sum(tpots) / len(tpots) if tpots else 0.0
-        n_tok = sum(len(r.tokens) for r in finished)
         print(f"[launch.serve] batch={args.batch} TTFT={ttft*1e3:.1f}ms "
               f"TPOT={tpot*1e3:.1f}ms tok/s={n_tok / wall:.1f} mode={mode}")
     else:
@@ -365,7 +471,19 @@ def main():
         print("[launch.serve] seq0:", list(reqs[0].tokens))
     engine.shutdown(deadline_s=30.0)
     print(f"[launch.serve] health={HEALTH.state} ({HEALTH.detail})")
+    return ServeResult(requests=reqs, engine=engine, stats=st,
+                       wall_s=wall, tok_s=n_tok / wall, warmup_s=warmup_s,
+                       weight_stats=weight_stats,
+                       verify=verify)
 
 
 if __name__ == "__main__":
-    main()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    res = main()
+    # exit nonzero when the engine had to evict work it had admitted (a
+    # step fault or an abort), or the codec round trip found a mismatch
+    bad = (res.stats["evicted_fault"] + res.stats["evicted_abort"]
+           + (res.verify or {}).get("mismatched", 0))
+    raise SystemExit(1 if bad else 0)

@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.optim import adamw
 from repro.runtime import elastic, sharding
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/repro_ckpt")
     ap.add_argument("--mesh", default=None, help="e.g. 16x16 (default: auto)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     cfg = dataclasses.replace(cfg, scan_layers=True)

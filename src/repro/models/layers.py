@@ -46,6 +46,19 @@ def weight_matmul(w, x, eq: str):
     return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32)
 
 
+def _two_rows(x2d):
+    """Pad a one-row (1, K) operand to two rows.  This only matters on the
+    CPU: XLA:CPU runs a one-row dot through a matrix-vector kernel whose
+    f32 accumulation order differs from the matrix-matrix kernel it uses
+    for two or more rows, so without this a request's logits would depend
+    on how many rows share its step (batch-1 prefill head vs batched
+    decode) and on whether the row was sliced inside or outside the jit.
+    Elsewhere it costs one copy of a (1, K) row."""
+    if x2d.shape[0] != 1:
+        return x2d
+    return jnp.concatenate([x2d, x2d], axis=0)
+
+
 def safe_einsum(eq, a, b):
     """einsum with f32 accumulation.
 
@@ -350,6 +363,20 @@ def lm_logits(x, head):
     """x (B, T, D) @ head (D, V) -> f32 logits."""
     return jnp.einsum("btd,dv->btv", x, head,
                       preferred_element_type=jnp.float32)
+
+
+def serve_logits(x, head):
+    """Serving head: x (B, T, D) @ head (D, V) -> f32 logits through the
+    canonical tiled serve matmul of every weight mode
+    (``runtime.weights.dense_matmul``),
+    so the logits are the same bits whether the head is a raw array or was
+    just decoded from ENEC streams, and whether it ran alone or in a batch
+    (a one-row operand is padded to two, see :func:`_two_rows`)."""
+    from repro.runtime.weights import dense_matmul
+    b, t, d = x.shape
+    x2 = x.reshape(b * t, d)
+    out = dense_matmul(_two_rows(x2), head)[:b * t]
+    return out.reshape(b, t, -1)
 
 
 def cross_entropy(logits, targets, mask=None):

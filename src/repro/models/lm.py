@@ -36,7 +36,7 @@ from . import xlstm as xlstm_lib
 from .layers import (ACT_DTYPE, AttnParamsShape, attention_block,
                      attention_decode_block, cross_entropy, dense_init,
                      embed_init, embed_tokens, init_attention, init_mlp,
-                     lm_logits, mlp_block, rms_norm)
+                     lm_logits, mlp_block, rms_norm, serve_logits)
 
 
 class BlockDesc(NamedTuple):
@@ -351,7 +351,7 @@ def prefill_fn(params, cfg, batch, max_len: int):
     x, caches, _, head, prefix_len = forward(params, cfg, batch,
                                              want_cache=True)
     b, t = x.shape[0], x.shape[1]
-    logits = lm_logits(x[:, -1:], head)[:, 0]  # forward() already normed x
+    logits = serve_logits(x[:, -1:], head)[:, 0]  # forward() normed x
     cache = init_cache(cfg, b, max_len)
     # install prefill state: attn K/V into the cache prefix, SSM/xLSTM final
     # states wholesale
@@ -383,7 +383,7 @@ def decode_fn(params, cfg, cache, tokens):
     if n_periods == 0:  # 0-layer variant used by the dry-run cost protocol
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = embed.T if cfg.tie_embeddings else _dense_leaf(params["head"])
-        return lm_logits(x, head)[:, 0], dict(cache, lengths=lengths + 1)
+        return serve_logits(x, head)[:, 0], dict(cache, lengths=lengths + 1)
 
     def period_body(x, sliced_params, sliced_cache):
         new_entries = []
@@ -427,4 +427,4 @@ def decode_fn(params, cfg, cache, tokens):
         cache = {"entries": new_entries, "lengths": lengths + 1}
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = embed.T if cfg.tie_embeddings else _dense_leaf(params["head"])
-    return lm_logits(x, head)[:, 0], cache
+    return serve_logits(x, head)[:, 0], cache

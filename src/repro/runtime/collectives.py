@@ -39,7 +39,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import codec as block_codec
@@ -135,11 +135,29 @@ def place_serving_tree(tree, mesh: Mesh, axis: str = MODEL_AXIS):
 # compressed-bytes all-gather
 # ---------------------------------------------------------------------------
 
-def _replicate(a, mesh: Mesh):
-    ns = NamedSharding(mesh, P(*((None,) * jnp.ndim(a))))
-    if isinstance(a, jax.core.Tracer):
-        return jax.lax.with_sharding_constraint(a, ns)
-    return jax.device_put(a, ns)
+def _gather_shards(a, mesh: Mesh, axis: str, shard_dim: int):
+    """All-gather the stream array ``a``, sharded over ``axis`` on its
+    shard dim, into a replicated array with that dim merged into the block
+    dim after it: ``(..., S, Bs, ...) -> (..., S*Bs, ...)``.
+
+    The gather concatenates the devices' block ranges itself
+    (``all_gather(tiled=True)`` inside ``shard_map``).  Gathering the
+    ``(S, Bs, w)`` layout and merging its dims afterwards is the same bytes,
+    but on a TPU that uint8 reshape is a relayout that compiles for tens of
+    seconds per stream when ``Bs`` is not a multiple of 32 (35 s for one
+    stream of StableLM-3B's embedding, ahead-of-time compile for a v5e; the
+    tiled gather compiles in 0.5 s)."""
+    spec = P(*((None,) * shard_dim + (axis,)))
+
+    def local(x):
+        shape = x.shape
+        x = x.reshape(shape[:shard_dim]
+                      + (shape[shard_dim] * shape[shard_dim + 1],)
+                      + shape[shard_dim + 2:])
+        return jax.lax.all_gather(x, axis, axis=shard_dim, tiled=True)
+
+    return shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(),
+                     check_vma=False)(a)
 
 
 def stream_nbytes(ct: CompressedTensor) -> int:
@@ -158,9 +176,13 @@ def gather_ct(ct: CompressedTensor, mesh: Mesh, axis: str = MODEL_AXIS,
     traffic for an ``A``-way axis, attributed to the ``d2d_allgather``
     ledger link (never the dense equivalent ``(A-1) x nbytes_raw``).
 
+    The gathered tensor comes back in the one-shard layout (``shards=1``:
+    the shard dim merged into the block dim by the gather itself, see
+    :func:`_gather_shards`), which decodes bit-identically.
+
     No-op (and nothing counted) for raw/const/unsharded tensors or when
-    ``ct.shards`` doesn't divide the axis.  Works eagerly (``device_put``)
-    and inside jit (``with_sharding_constraint`` — counted at trace time).
+    ``ct.shards`` doesn't divide the axis.  Works eagerly and inside jit
+    (counted at trace time).
 
     A tensor consumed at several call sites (e.g. a tied embed/head handle)
     is gathered ONCE: the eager gathered result is cached on the source
@@ -177,8 +199,11 @@ def gather_ct(ct: CompressedTensor, mesh: Mesh, axis: str = MODEL_AXIS,
     n_leaves = len(jax.tree.leaves(ct.streams))
     (codec or current_codec()).count_link(
         "d2d_allgather", stream_nbytes(ct) * (A - 1), ops=n_leaves)
-    streams = jax.tree.map(lambda a: _replicate(a, mesh), ct.streams)
-    out = dataclasses.replace(ct, streams=streams)
+    # the shard dim sits right before the block dim: (..., S, Bs[, w])
+    lead = ct.streams.high_len.ndim
+    streams = block_codec.BlockStreams(*(
+        _gather_shards(a, mesh, axis, lead - 2) for a in ct.streams))
+    out = dataclasses.replace(ct, streams=streams, shards=1)
     cached = getattr(ct, "_wire_bytes", None)
     if cached is not None:   # keep the lazily-filled wire-size cache
         out._wire_bytes = cached
@@ -198,6 +223,19 @@ def maybe_gather_ct(ct: CompressedTensor, codec=None) -> CompressedTensor:
         return ct
     mesh, axis = ctx
     return gather_ct(ct, mesh, axis, codec)
+
+
+def replicated_local(fn, *args):
+    """``fn(*args)`` as per-device code under the ambient serving mesh:
+    the inputs are taken replicated (gathered compressed streams, the
+    replicated activations) and ``fn`` runs whole on every device.  XLA
+    cannot partition a Pallas kernel, so every decode and fused matmul of
+    a gathered handle goes through here.  A plain call without a mesh."""
+    ctx = serving_mesh()
+    if ctx is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=ctx[0], in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------

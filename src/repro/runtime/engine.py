@@ -172,11 +172,7 @@ class Engine:
         self._extra_context = extra_context
 
         self.queue = AdmissionQueue(config.queue_depth)
-        self.governor = OverloadGovernor(
-            watchdog_s=config.watchdog_s,
-            overload_factor=config.overload_factor,
-            warmup_steps=config.warmup_steps,
-            recovery_steps=config.recovery_steps)
+        self.reset_governor()
 
         s = config.max_slots
         if s < 1:
@@ -281,6 +277,25 @@ class Engine:
             donate = (1,) if jax.default_backend() != "cpu" else ()
             self._step_fns[bucket] = jax.jit(fn, donate_argnums=donate)
         return self._step_fns[bucket]
+
+    def reset_governor(self) -> None:
+        """Start the overload governor afresh — after a warm-up pass, whose
+        compiling steps say nothing about load."""
+        c = self.config
+        self.governor = OverloadGovernor(
+            watchdog_s=c.watchdog_s, overload_factor=c.overload_factor,
+            warmup_steps=c.warmup_steps, recovery_steps=c.recovery_steps)
+
+    def step_hlo(self, bucket: Optional[int] = None) -> str:
+        """The decode step over ``bucket`` slots (default: the whole ring)
+        as lowered for the compiler — shows which kernels a step runs (a
+        native Pallas kernel is a ``tpu_custom_call``) without compiling."""
+        bucket = bucket or self.config.max_slots
+        self._ensure_cache()
+        with self._trace_ctx():
+            return self._step_fn(bucket).lower(
+                self.params, self._entries, self._tokens,
+                self._lengths).as_text()
 
     # -- submission ---------------------------------------------------------
 

@@ -60,6 +60,18 @@ OVERLAP_MODES = ("off", "on", "auto")
 SCAN_UNROLL_WINDOW = 8
 
 
+def default_unroll(n_periods: int) -> int:
+    """Layers per pipelined scan body: :data:`SCAN_UNROLL_WINDOW` (capped
+    at the stack), except on a TPU, where it is one.  XLA:TPU rounds layer
+    math (norms, softmax) differently once several layers are inlined into
+    one body: on a v5e an unrolled pipeline moved nearly every logit bit
+    away from the serial scan, while one layer per body keeps the layer
+    math in the same loop-body form as the serial scan."""
+    if jax.default_backend() == "tpu":
+        return 1
+    return min(n_periods, SCAN_UNROLL_WINDOW)
+
+
 def overlap_enabled(mode: str, period) -> bool:
     """Should the layer loop over ``period`` run pipelined?  "off" never;
     "on"/"auto" whenever there is a stream to prefetch (a tree with no
@@ -118,7 +130,7 @@ def decode_layer(schedule: OverlapSchedule, index, codec=None) -> tuple:
     the per-step prefetch dispatch.  Returns the finished dense weights
     (un-permuted, target dtype) in slot order, bit-identical to
     ``StreamedWeight.materialize`` on the same slice."""
-    from repro.runtime.collectives import maybe_gather_ct
+    from repro.runtime.collectives import maybe_gather_ct, replicated_local
     codec = codec or current_codec()
     handles = [schedule.leaves[s] for s in schedule.slots]
     # slice layer `index`, then (under an ambient serving mesh) gather the
@@ -131,7 +143,8 @@ def decode_layer(schedule: OverlapSchedule, index, codec=None) -> tuple:
                                               h.ct.streams)),
                codec)
            for h in handles]
-    decs = codec.decompress_stacked_many(cts, exact=True)
+    decs = replicated_local(
+        lambda cts: codec.decompress_stacked_many(cts, exact=True), cts)
     return tuple(
         jnp.moveaxis(d, 0, h.tp_axis).astype(jnp.dtype(h.dtype_str))
         for h, d in zip(handles, decs))
@@ -178,7 +191,7 @@ def pipeline_scan(schedule: OverlapSchedule, apply_fn: Callable, carry0, *,
     with the serial scan.
 
     The loop is modulo-unrolled by ``unroll`` layers (default
-    ``min(P, SCAN_UNROLL_WINDOW)``): inside a window the decode→consume
+    :func:`default_unroll`): inside a window the decode→consume
     handoff is ordinary dataflow the backend schedules and fuses freely;
     the double-buffer rides the loop carry only across window boundaries.
     Returns ``(carry, ys)`` with ``ys`` stacked over all P layers like a
@@ -187,7 +200,7 @@ def pipeline_scan(schedule: OverlapSchedule, apply_fn: Callable, carry0, *,
     codec = codec or current_codec()
     P = schedule.n_periods
     if unroll is None:
-        unroll = min(P, SCAN_UNROLL_WINDOW)
+        unroll = default_unroll(P)
     dec = decode_layer(schedule, 0, codec)
     slots = set(schedule.slots)
     xs_rest = [None if i in slots else leaf

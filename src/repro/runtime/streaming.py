@@ -79,9 +79,12 @@ def stream_eligible(pstr: str, shape, dtype,
     nbytes = int(np.prod(shape)) * jnp.dtype(dtype).itemsize
     if nbytes < min_bytes or jnp.dtype(dtype) not in SUPPORTED_FLOAT_DTYPES:
         return False
-    if len(shape) == 2:
-        return True
     stacked = "period" in pstr or "stack" in pstr
+    if len(shape) == 2:
+        # a 2-D leaf inside the layer stack is an (L, d) stack of vectors
+        # (norm gains): the layer loop slices it, so it cannot be an L=1
+        # flat stream
+        return not stacked
     return stacked and len(shape) >= 3
 
 
@@ -130,8 +133,9 @@ def _is_matmul_pos(pstr: str, ndim: int) -> bool:
 def serving_job(pstr: str, leaf, mode: str,
                 min_bytes: int = MIN_STREAM_BYTES) -> Optional[dict]:
     """The per-leaf compression plan for a compressing mode ("stream" /
-    "fused"): which layout to encode (``arr``) and the handle metadata to
-    attach.  ``None`` means the leaf is not eligible and stays raw/dense.
+    "fused"): which layout to encode (``arr()`` builds it when its encode
+    batch runs) and the handle metadata to attach.  ``None`` means the
+    leaf is not eligible and stays raw/dense.
 
     Shared between :func:`assign_weight_modes` and the checkpoint writer's
     ``serving_layout`` path, so a checkpoint stores byte-for-byte the stream
@@ -145,16 +149,17 @@ def serving_job(pstr: str, leaf, mode: str,
         # moveaxis layout; the flat handle squeezes the stack dim back out
         tp_axis = _tp_axis_for(pstr, leaf.shape)
         return dict(kind="stream", leaf=leaf,
-                    arr=jnp.moveaxis(leaf, tp_axis, 0)[None],
+                    arr=lambda: jnp.moveaxis(leaf, tp_axis, 0)[None],
                     tp_axis=tp_axis, layer_shape=leaf.shape,
                     matmul_pos=False, flat=True)
     matmul_pos = _is_matmul_pos(pstr, leaf.ndim)
     if mode == "fused" and matmul_pos:
-        return dict(kind="fused", leaf=leaf, arr=matmul_tiles(leaf),
+        return dict(kind="fused", leaf=leaf,
+                    arr=lambda: matmul_tiles(leaf),
                     k=leaf.shape[1], n=leaf.shape[2], matmul_pos=True)
     tp_axis = _tp_axis_for(pstr, leaf.shape[1:])
     return dict(kind="stream", leaf=leaf,
-                arr=jnp.moveaxis(leaf, 1 + tp_axis, 1),
+                arr=lambda: jnp.moveaxis(leaf, 1 + tp_axis, 1),
                 tp_axis=tp_axis, layer_shape=leaf.shape[1:],
                 matmul_pos=matmul_pos)
 
@@ -241,26 +246,49 @@ def assign_weight_modes(params, *, mode: str = "fused",
             out[slot] = leaf
             continue
         job["slot"] = slot
-        job["shards"] = (fused_shards(job["k"], job["n"], shards)
-                         if job["kind"] == "fused" else shards)
         jobs.append(job)
-    codec = codec or current_codec()
-    # one batched encode per distinct shard width (fused leaves whose tile
-    # count doesn't divide `shards` drop to 1; everything else shares one
-    # O(#buckets) pass)
-    cts = [None] * len(jobs)
-    by_shards: dict = {}
-    for idx, j in enumerate(jobs):
-        by_shards.setdefault(j["shards"], []).append(idx)
-    for job_shards, idxs in sorted(by_shards.items()):
-        group = codec.compress_stacked_many(
-            [jobs[i]["arr"] for i in idxs], p=shared_params,
-            shards=job_shards)
-        for i, ct in zip(idxs, group):
-            cts[i] = ct
+    cts = compress_serving_jobs(jobs, shards, codec or current_codec(),
+                                shared_params)
     for j, ct in zip(jobs, cts):
         out[j["slot"]] = build_serving_handle(j, ct)
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+# raw bytes one batched encode pass may take in: a pass holds its inputs'
+# block copies, their concatenation and the streams at once, so batching a
+# whole full-width model in one pass needs ~3x its size in HBM
+ENCODE_BATCH_BYTES = 1 << 30
+
+
+def compress_serving_jobs(jobs, shards: int, codec, shared_params=None):
+    """Compress :func:`serving_job` plans, one ``Optional[CompressedTensor]``
+    per job.  One batched pass (O(#buckets) encode dispatches) per shard
+    width — fused leaves whose tile count doesn't divide ``shards`` drop to
+    1 — split further only where a pass would exceed
+    :data:`ENCODE_BATCH_BYTES` of raw weights.  Each job's encode layout
+    is built just before its pass and dropped after it."""
+    cts = [None] * len(jobs)
+    by_shards: dict = {}
+    for idx, j in enumerate(jobs):
+        width = (fused_shards(j["k"], j["n"], shards)
+                 if j["kind"] == "fused" else shards)
+        by_shards.setdefault(width, []).append(idx)
+    for width, idxs in sorted(by_shards.items()):
+        batches, nbytes = [[]], 0
+        for i in idxs:
+            size = jobs[i]["leaf"].size * jobs[i]["leaf"].dtype.itemsize
+            if batches[-1] and nbytes + size > ENCODE_BATCH_BYTES:
+                batches.append([])
+                nbytes = 0
+            batches[-1].append(i)
+            nbytes += size
+        for batch in batches:
+            group = codec.compress_stacked_many(
+                [jobs[i]["arr"]() for i in batch], p=shared_params,
+                shards=width)
+            for i, ct in zip(batch, group):
+                cts[i] = ct
+    return cts
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,9 @@ Every mode's ``matmul`` realizes the *same* canonical contraction — the
 ``kernels.ref.tiled_matmul_ref``, which is the exact schedule the fused
 Pallas kernel executes — so serve logits are bit-identical across modes:
 the mode changes where weight bytes live and when they decompress, never
-the numerics.
+the numerics.  On a TPU the dense and streamed modes run that contraction
+as the Pallas kernel ``kernels.decompress_matmul.tiled_matmul`` (see
+:func:`dense_matmul`).
 
 Handles are registered pytrees whose array fields carry a leading ``(L,)``
 layer-stack dim; ``lax.scan`` (or ``tree.map(a[i])`` on the unrolled path)
@@ -36,7 +38,17 @@ import jax.numpy as jnp
 
 from repro.core.api import MATMUL_TILE, CompressedTensor
 from repro.core.codec_api import current_codec
-from repro.kernels.ref import tiled_matmul_ref
+
+
+def dense_matmul(x, w):
+    """The canonical serve matmul of a materialized (K, N) weight
+    (``kernels.ops.tiled_matmul``: the Pallas kernel on a TPU, whose
+    arithmetic does not depend on the program around it; the jnp schedule
+    elsewhere).  Under a serving mesh it runs replicated on every device,
+    like the decode and fused kernels."""
+    from repro.kernels import ops  # lazy: keep module import light
+    from repro.runtime.collectives import replicated_local
+    return replicated_local(ops.tiled_matmul, x, w)
 
 
 class WeightHandle:
@@ -65,7 +77,7 @@ class DenseWeight(WeightHandle):
         return self.w
 
     def matmul(self, x):
-        return tiled_matmul_ref(x, self.w)
+        return dense_matmul(x, self.w)
 
 
 @jax.tree_util.register_dataclass
@@ -102,14 +114,16 @@ class StreamedWeight(WeightHandle):
         # as compressed bytes (collectives.maybe_gather_ct), then ONE local
         # decode runs on every device — the interconnect never carries the
         # dense weight.
-        from repro.runtime.collectives import maybe_gather_ct
+        from repro.runtime.collectives import maybe_gather_ct, \
+            replicated_local
         ct = maybe_gather_ct(self.ct, codec)
-        w_perm = (codec or current_codec()).decompress_array(ct)
+        w_perm = replicated_local((codec or current_codec()).decompress_array,
+                                  ct)
         w = jnp.moveaxis(w_perm, 0, self.tp_axis)
         return w.astype(jnp.dtype(self.dtype_str))
 
     def matmul(self, x):
-        return tiled_matmul_ref(x, self.materialize())
+        return dense_matmul(x, self.materialize())
 
 
 @jax.tree_util.register_dataclass
@@ -126,9 +140,11 @@ class FusedWeight(WeightHandle):
 
     def matmul(self, x):
         from repro.kernels import ops  # lazy: keep module import light
-        from repro.runtime.collectives import maybe_gather_ct
-        return ops.decompress_matmul(x, maybe_gather_ct(self.ct),
-                                     self.k, self.n)
+        from repro.runtime.collectives import maybe_gather_ct, \
+            replicated_local
+        return replicated_local(
+            lambda x, ct: ops.decompress_matmul(x, ct, self.k, self.n),
+            x, maybe_gather_ct(self.ct))
 
     def materialize(self, codec=None):
         from repro.runtime.collectives import maybe_gather_ct

@@ -217,13 +217,15 @@ def test_segment_gather_tail_padded_block():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(bits))
 
 
-def test_decode_kernel_multiple_blocks_per_grid_step():
-    from repro.kernels.enec_decode import blocks_per_step
-    assert blocks_per_step(8, 1024) == 8
-    assert blocks_per_step(4, 16384) == 1
-    assert blocks_per_step(6, 1024) == 2          # must divide the total
+@pytest.mark.parametrize("nblocks", [8, 20, 6])
+def test_decode_kernel_multiple_blocks_per_grid_step(nblocks):
+    # one block per sublane row, 8 per grid step; 20 blocks leave a partial
+    # last step whose padding rows are never written back, and fewer than 8
+    # blocks decode in a single step of exactly that many rows
+    from repro.kernels.lanes import step_rows
+    assert step_rows(nblocks) == min(8, nblocks)
     n_elems = 1024
-    x = _make(8 * n_elems, 12)
+    x = _make(nblocks * n_elems, 12)
     p = params_mod.search_for_array(np.asarray(jax.device_get(x)), BF16,
                                     block_elems=n_elems)
     bits = codec.to_blocks(x, BF16, n_elems)

@@ -103,7 +103,7 @@ def test_compressed_allreduce_bit_identical_2pods():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_mesh
     from repro.optim.grad_compress import compressed_allreduce
     from repro.core import search_for_array, BF16

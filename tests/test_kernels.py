@@ -160,6 +160,22 @@ def test_fused_matmul_stacked_streams_slice_in_scan():
                                       np.asarray(want).view(np.uint32))
 
 
+# dense tile grid of the serve matmul: one and several strips per grid
+# step (N tiles 2, 3, 4), M above the 128-row block, ragged K/N
+@pytest.mark.parametrize("mkn", [(8, 256, 384), (4, 384, 512),
+                                 (130, 256, 256), (8, 250, 120)])
+def test_tiled_matmul_kernel_matches_schedule(mkn):
+    from repro.kernels.decompress_matmul import tiled_matmul
+    m, k, n = mkn
+    wm, ct, x = _fused_case(m, k, n, jnp.bfloat16, seed=m + k + n)
+    got = np.asarray(tiled_matmul(x, wm, interpret=True)).view(np.uint32)
+    want = np.asarray(ref.tiled_matmul_ref(x, wm)).view(np.uint32)
+    np.testing.assert_array_equal(got, want)
+    # and the fused kernel on the compressed tiles of the same weight
+    fused = np.asarray(decompress_matmul(x, ct, k, n)).view(np.uint32)
+    np.testing.assert_array_equal(got, fused)
+
+
 def test_kernel_jit_wrappers():
     bits, fmt, p = _blocks_for("bf16", 2048, nblocks=1)
     s = ops.encode_blocks(bits, fmt, p, use_pallas=False)

@@ -80,6 +80,26 @@ def test_overlap_logits_bit_identical_all_modes(mode):
                       f"mode={mode} overlap on vs off")
 
 
+def test_overlap_one_layer_per_body_bit_identical(monkeypatch):
+    """The TPU schedule of the pipelined scan (one layer per scan body,
+    ``overlap.default_unroll`` there) keeps the overlap-on/off bit
+    identity of the windowed schedule other backends use."""
+    import repro.runtime.overlap as overlap_mod
+    monkeypatch.setattr(overlap_mod, "default_unroll", lambda n: 1)
+    cfg = dataclasses.replace(get_smoke_config("llama3_2_1b"),
+                              scan_layers=True, n_layers=3)
+    model_off = build_model(dataclasses.replace(cfg, overlap="off"))
+    model_on = build_model(dataclasses.replace(cfg, overlap="on"))
+    params = model_off.init(jax.random.key(0))
+    tree = assign_weight_modes(params, mode="stream", min_bytes=1024,
+                               shards=2)
+    pb = {"tokens": jax.random.randint(jax.random.key(1), (2, 8), 0,
+                                       cfg.vocab_size)}
+    _assert_bit_equal(_serve(model_off, tree, pb, 16),
+                      _serve(model_on, tree, pb, 16),
+                      "one layer per pipelined body vs serial scan")
+
+
 def test_overlap_scan_unrolled_parity():
     """scan and unrolled pipelined drivers agree numerically; bit-equality
     across the two drivers is NOT required (XLA fuses — and rounds —
