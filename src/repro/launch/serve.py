@@ -64,7 +64,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.core.codec_api import Codec
 from repro.models import build_model
-from repro.runtime.engine import Engine, EngineConfig, ServerHealth
+from repro.runtime.engine import PHASES, Engine, EngineConfig, ServerHealth
 from repro.runtime.streaming import assign_weight_modes, mode_mix, \
     stream_stats
 
@@ -455,6 +455,13 @@ def main(argv=None) -> ServeResult:
           f"timed_out={st['timed_out']} shed={st['shed']} "
           f"evicted={evicted} rejected={st['rejected']} "
           f"governor={engine.governor.state}")
+    waited = 1e3 * st["queue_wait_s"] / max(1, st["admitted"])
+    phases = {p: 1e3 * sum(getattr(r, p) for r in engine.step_phases)
+              / max(1, len(engine.step_phases)) for p in PHASES}
+    print(f"[launch.serve] scheduler: queue_wait={waited:.2f}ms/request "
+          f"steps_with_prefill={st['steps_with_prefill']}/{st['steps']} "
+          "step phases (mean ms): "
+          + " ".join(f"{p}={v:.2f}" for p, v in phases.items()))
     if expert_store is not None:
         es = expert_store.stats()
         dec_ms = (1e3 * sum(engine.step_decode_s)

@@ -45,16 +45,25 @@ Design points (each is load-bearing for a robustness claim):
 * **Graceful drain.**  ``shutdown(deadline_s)`` refuses new work, sheds
   the queue, finishes in-flight requests until the deadline, and evicts
   stragglers as ``aborted``.
+
+* **Observable on the profiler's clock.**  The jitted programs are named
+  ``engine_prefill``, ``engine_install`` and ``engine_decode_step`` (the
+  device shows them as ``jit_engine_*`` modules), and the host work is
+  wrapped in ``engine.*`` spans (``jax.profiler.TraceAnnotation``; with no
+  profiler session each is one cheap no-op), with a ``python.gc`` span
+  around every garbage collection (docs/SERVING.md, "Observability").
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import threading
 import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime import faults as rt_faults
 from repro.runtime.admission import (AdmissionQueue, OverloadGovernor,
@@ -128,6 +137,82 @@ class EngineConfig:
         return self.max_prompt_len + self.max_new_tokens
 
 
+PHASES = ("admit", "dispatch", "wait", "emit", "gc")
+
+
+@dataclasses.dataclass
+class StepPhases:
+    """Seconds of one decode step's :meth:`Engine.step` by phase, on the
+    engine's clock: ``admit`` (deadline sweep, admission and its
+    prefills, fault probes), ``dispatch`` (the step call), ``wait`` (the
+    token fetch), ``emit`` (the bookkeeping after it).  Garbage
+    collection pauses are taken out of the phase they fell in and counted
+    under ``gc`` (timed on ``time.perf_counter``)."""
+    admit: float = 0.0
+    dispatch: float = 0.0
+    wait: float = 0.0
+    emit: float = 0.0
+    gc: float = 0.0
+
+    def largest(self) -> str:
+        """The phase that took most of the step (the cause of a stall)."""
+        return max(PHASES, key=lambda p: getattr(self, p))
+
+
+class _GcTimer:
+    """Seconds the process has spent in the garbage collector, and a
+    ``python.gc`` span (with the collection's ``generation``) around each
+    collection.  ``gc.callbacks`` is process-wide and a collection never
+    runs inside another, so one instance serves every engine."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._t0 = 0.0
+        self._span = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._span = TraceAnnotation("python.gc",
+                                         generation=info["generation"])
+            self._span.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._span is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+
+_GC_TIMER: Optional[_GcTimer] = None
+
+
+def _gc_timer() -> _GcTimer:
+    """The process's :class:`_GcTimer`, installed on first use."""
+    global _GC_TIMER
+    if _GC_TIMER is None:
+        _GC_TIMER = _GcTimer()
+        gc.callbacks.append(_GC_TIMER)
+    return _GC_TIMER
+
+
+class _PhaseClock:
+    """Splits one step into :class:`StepPhases`: each :meth:`close` gives
+    the time since the previous boundary to a phase, less the garbage
+    collection inside it, which goes to ``gc``."""
+
+    def __init__(self, clock: Callable[[], float], gc_timer: _GcTimer):
+        self.clock, self.gc_timer = clock, gc_timer
+        self.phases = StepPhases()
+        self._t, self._gc = clock(), gc_timer.seconds
+
+    def close(self, phase: str) -> None:
+        t, g = self.clock(), self.gc_timer.seconds
+        paused = g - self._gc
+        self.phases.gc += paused
+        setattr(self.phases, phase,
+                getattr(self.phases, phase) + (t - self._t) - paused)
+        self._t, self._gc = t, g
+
+
 def _next_bucket(n: int, cap: int) -> int:
     """Smallest power of two >= n, capped at ``cap`` (the final bucket)."""
     b = 1
@@ -186,12 +271,18 @@ class Engine:
         self._install_fn = None
 
         self.results: Dict[int, Request] = {}
+        # queue_wait_s: summed admit_s - submit_s of admitted requests;
+        # steps_with_prefill: decode steps whose step() ran a prefill
         self.counters = {"submitted": 0, "admitted": 0, "done": 0,
                          "timed_out": 0, "rejected": 0, "shed": 0,
                          "evicted_deadline": 0, "evicted_fault": 0,
                          "evicted_abort": 0, "steps": 0, "prefills": 0,
-                         "fault_retries": 0}
+                         "fault_retries": 0, "queue_wait_s": 0.0,
+                         "steps_with_prefill": 0}
         self.step_times_s: List[float] = []
+        # per decode step, beside step_times_s: where its step() went
+        self.step_phases: List[StepPhases] = []
+        self._gc_timer = _gc_timer()
         # per decode step: expert-cache MISS decode seconds (0.0 on a
         # fully-resident step) — step_times_s[i] - step_decode_s[i] is the
         # compute-only cost, making the cache-budget latency knob visible
@@ -227,13 +318,13 @@ class Engine:
             import jax.numpy as jnp
             model, max_len = self.model, self.config.max_len
 
-            def fn(params, tokens):
+            def engine_prefill(params, tokens):
                 logits, cache = model.prefill_fn(params, {"tokens": tokens},
                                                  max_len)
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
                 return tok, logits, cache["entries"]
 
-            self._prefill_fns[plen] = jax.jit(fn)
+            self._prefill_fns[plen] = jax.jit(engine_prefill)
         return self._prefill_fns[plen]
 
     def _install(self, req_entries, slot: int):
@@ -242,13 +333,13 @@ class Engine:
         import jax
 
         if self._install_fn is None:
-            def fn(entries, req_entries, slot):
+            def engine_install(entries, req_entries, slot):
                 return jax.tree.map(
                     lambda full, part: jax.lax.dynamic_update_slice_in_dim(
                         full, part, slot, axis=1),
                     entries, req_entries)
 
-            self._install_fn = jax.jit(fn)
+            self._install_fn = jax.jit(engine_install)
         self._entries = self._install_fn(self._entries, req_entries,
                                          np.int32(slot))
 
@@ -260,7 +351,7 @@ class Engine:
             import jax.numpy as jnp
             model = self.model
 
-            def fn(params, entries, tokens, lengths):
+            def engine_decode_step(params, entries, tokens, lengths):
                 sub = jax.tree.map(
                     lambda a: jax.lax.slice_in_dim(a, 0, bucket, axis=1),
                     entries)
@@ -275,7 +366,8 @@ class Engine:
                 return tok, logits, new_entries
 
             donate = (1,) if jax.default_backend() != "cpu" else ()
-            self._step_fns[bucket] = jax.jit(fn, donate_argnums=donate)
+            self._step_fns[bucket] = jax.jit(engine_decode_step,
+                                             donate_argnums=donate)
         return self._step_fns[bucket]
 
     def reset_governor(self) -> None:
@@ -330,11 +422,13 @@ class Engine:
             deadline_s=None if deadline_s is None else now + deadline_s,
             name=name)
         req.submit_s = now
-        self.counters["submitted"] += 1
-        self.results[req.rid] = req
-        ok, _ = self.queue.offer(req, overloaded=self.governor.overloaded)
-        if not ok:
-            self.counters["rejected"] += 1
+        with TraceAnnotation("engine.submit", rid=req.rid):
+            self.counters["submitted"] += 1
+            self.results[req.rid] = req
+            ok, _ = self.queue.offer(req,
+                                     overloaded=self.governor.overloaded)
+            if not ok:
+                self.counters["rejected"] += 1
         return req
 
     # -- lifecycle helpers --------------------------------------------------
@@ -400,126 +494,145 @@ class Engine:
 
     def _admit(self) -> int:
         """Fill free slots from the queue (lowest slot first, FIFO order);
-        each admission = one batch=1 prefill scattered into the ring."""
-        admitted = 0
-        while not self._draining and self.queue.peek_viable():
-            try:
-                slot = self._slots.index(None)
-            except ValueError:
-                break
-            req = self.queue.take()
-            if req is None:
-                break
-            now = self.clock()
-            if req.deadline_s is not None and now > req.deadline_s:
-                req.state, req.detail = "shed", "deadline"
-                req.finish_s = now
-                self.queue.counters["shed_deadline"] += 1
-                self.counters["shed"] += 1
-                continue
-            # step-fault probe BEFORE the prefill consumes compute
-            if rt_faults.active() is not None:
-                budget = None if req.deadline_s is None \
-                    else max(0.0, req.deadline_s - now)
-                before = self.retry.stats()["retries"]
+        each admission = one batch=1 prefill scattered into the ring.  The
+        ``engine.admit`` span opens only when a slot is free and a viable
+        request waits, so its count is the steps that admitted."""
+        if (self._draining or None not in self._slots
+                or not self.queue.peek_viable()):
+            return 0
+        with TraceAnnotation("engine.admit"):
+            admitted = 0
+            while not self._draining and self.queue.peek_viable():
                 try:
-                    self.retry.call(
-                        lambda r=req: rt_faults.check_step(r.key),
-                        describe=f"step:{req.key}", max_elapsed_s=budget)
-                except rt_faults.InjectedFault as e:
-                    req.finish_s = self.clock()
-                    req.state, req.detail = "evicted", "fault"
-                    self.counters["evicted_fault"] += 1
-                    self.health.transition(
-                        "degraded",
-                        f"step fault evicted {req.name} at admission: {e}")
+                    slot = self._slots.index(None)
+                except ValueError:
+                    break
+                req = self.queue.take()
+                if req is None:
+                    break
+                now = self.clock()
+                if req.deadline_s is not None and now > req.deadline_s:
+                    req.state, req.detail = "shed", "deadline"
+                    req.finish_s = now
+                    self.queue.counters["shed_deadline"] += 1
+                    self.counters["shed"] += 1
                     continue
-                finally:
-                    absorbed = self.retry.stats()["retries"] - before
-                    req.retries += absorbed
-                    self.counters["fault_retries"] += absorbed
-            req.admit_s = self.clock()
-            req.slot = slot
-            self._slots[slot] = req
-            req.state = "running"
-            self.counters["admitted"] += 1
-            self._run_prefill(req, slot)
-            admitted += 1
-            if req.finished:
-                continue
-            if len(req.tokens) >= req.max_new_tokens:
-                self._complete(req)
-        return admitted
+                # step-fault probe BEFORE the prefill consumes compute
+                if rt_faults.active() is not None:
+                    budget = None if req.deadline_s is None \
+                        else max(0.0, req.deadline_s - now)
+                    before = self.retry.stats()["retries"]
+                    try:
+                        self.retry.call(
+                            lambda r=req: rt_faults.check_step(r.key),
+                            describe=f"step:{req.key}", max_elapsed_s=budget)
+                    except rt_faults.InjectedFault as e:
+                        req.finish_s = self.clock()
+                        req.state, req.detail = "evicted", "fault"
+                        self.counters["evicted_fault"] += 1
+                        self.health.transition(
+                            "degraded",
+                            f"step fault evicted {req.name} at admission: {e}")
+                        continue
+                    finally:
+                        absorbed = self.retry.stats()["retries"] - before
+                        req.retries += absorbed
+                        self.counters["fault_retries"] += absorbed
+                req.admit_s = self.clock()
+                self.counters["queue_wait_s"] += req.admit_s - req.submit_s
+                req.slot = slot
+                self._slots[slot] = req
+                req.state = "running"
+                self.counters["admitted"] += 1
+                self._run_prefill(req, slot)
+                admitted += 1
+                if req.finished:
+                    continue
+                if len(req.tokens) >= req.max_new_tokens:
+                    self._complete(req)
+            return admitted
 
     def _run_prefill(self, req: Request, slot: int) -> None:
         import jax
 
-        self._ensure_cache()
-        self.counters["prefills"] += 1
-        fn = self._prefill_fn(req.prompt.size)
-        with self._trace_ctx():
-            tok, logits, req_entries = fn(self.params,
-                                          req.prompt[None, :])
-            jax.block_until_ready(tok)
-            self._install(req_entries, slot)
-        req.first_token_s = self.clock()
-        t = int(np.asarray(tok)[0])
-        req.tokens.append(t)
-        self._tokens[slot] = t
-        self._lengths[slot] = req.prompt.size
-        if self.config.collect_logits:
-            req.logits.append(np.asarray(logits)[0])
+        with TraceAnnotation("engine.prefill", rid=req.rid,
+                             plen=int(req.prompt.size), slot=slot):
+            self._ensure_cache()
+            self.counters["prefills"] += 1
+            fn = self._prefill_fn(req.prompt.size)
+            with self._trace_ctx():
+                tok, logits, req_entries = fn(self.params,
+                                              req.prompt[None, :])
+                with TraceAnnotation("engine.prefill.wait"):
+                    jax.block_until_ready(tok)
+                self._install(req_entries, slot)
+            req.first_token_s = self.clock()
+            with TraceAnnotation("engine.prefill.wait"):
+                t = int(np.asarray(tok)[0])
+            req.tokens.append(t)
+            self._tokens[slot] = t
+            self._lengths[slot] = req.prompt.size
+            if self.config.collect_logits:
+                req.logits.append(np.asarray(logits)[0])
 
-    def _decode_step(self) -> None:
-        import jax
-
+    def _decode_step(self, prefills: int, phase: _PhaseClock) -> None:
         active = self._active()
         bucket = _next_bucket(max(r.slot for r in active) + 1,
                               self.config.max_slots)
-        fn = self._step_fn(bucket)
-        dec0 = (self.expert_store.decode_seconds()
-                if self.expert_store is not None else 0.0)
-        t0 = self.clock()
-        with self._trace_ctx():
-            # real (non-injected) transient runtime errors ride the same
-            # retry policy as checkpoint I/O; a persistent failure poisons
-            # the whole batch — evict it and degrade rather than die
-            try:
-                tok, logits, new_entries = self.retry.call(
-                    lambda: fn(self.params, self._entries, self._tokens,
-                               self._lengths),
-                    describe=f"decode_step:b{bucket}")
-                np_tok = np.asarray(tok)
-            except OSError as e:
+        with TraceAnnotation("engine.decode", bucket=bucket,
+                             rows=len(active)):
+            fn = self._step_fn(bucket)
+            dec0 = (self.expert_store.decode_seconds()
+                    if self.expert_store is not None else 0.0)
+            t0 = self.clock()
+            with self._trace_ctx():
+                # real (non-injected) transient runtime errors ride the same
+                # retry policy as checkpoint I/O; a persistent failure poisons
+                # the whole batch — evict it and degrade rather than die
+                try:
+                    with TraceAnnotation("engine.decode.dispatch"):
+                        tok, logits, new_entries = self.retry.call(
+                            lambda: fn(self.params, self._entries,
+                                       self._tokens, self._lengths),
+                            describe=f"decode_step:b{bucket}")
+                    phase.close("dispatch")
+                    with TraceAnnotation("engine.decode.wait"):
+                        np_tok = np.asarray(tok)
+                    phase.close("wait")
+                except OSError as e:
+                    for req in active:
+                        self._finish(req, "evicted", "fault")
+                    self.health.transition(
+                        "degraded", f"decode step failed, batch evicted: {e}")
+                    return
+            with TraceAnnotation("engine.decode.emit"):
+                self._entries = new_entries
+                dt = self.clock() - t0
+                self.counters["steps"] += 1
+                self.counters["steps_with_prefill"] += prefills > 0
+                self.step_times_s.append(dt)
+                self.step_decode_s.append(
+                    (self.expert_store.decode_seconds() - dec0)
+                    if self.expert_store is not None else 0.0)
+                if self.governor.observe_step(dt):
+                    for req in self.queue.shed_lowest_priority(
+                            self.config.shed_per_trip, reason="overload"):
+                        self.counters["shed"] += 1
+                        req.finish_s = self.clock()
+                np_logits = np.asarray(logits) if self.config.collect_logits \
+                    else None
                 for req in active:
-                    self._finish(req, "evicted", "fault")
-                self.health.transition(
-                    "degraded", f"decode step failed, batch evicted: {e}")
-                return
-        self._entries = new_entries
-        dt = self.clock() - t0
-        self.counters["steps"] += 1
-        self.step_times_s.append(dt)
-        self.step_decode_s.append(
-            (self.expert_store.decode_seconds() - dec0)
-            if self.expert_store is not None else 0.0)
-        if self.governor.observe_step(dt):
-            for req in self.queue.shed_lowest_priority(
-                    self.config.shed_per_trip, reason="overload"):
-                self.counters["shed"] += 1
-                req.finish_s = self.clock()
-        np_logits = np.asarray(logits) if self.config.collect_logits \
-            else None
-        for req in active:
-            slot = req.slot
-            t = int(np_tok[slot])
-            req.tokens.append(t)
-            self._tokens[slot] = t
-            self._lengths[slot] += 1
-            if np_logits is not None:
-                req.logits.append(np_logits[slot])
-            if len(req.tokens) >= req.max_new_tokens:
-                self._complete(req)
+                    slot = req.slot
+                    t = int(np_tok[slot])
+                    req.tokens.append(t)
+                    self._tokens[slot] = t
+                    self._lengths[slot] += 1
+                    if np_logits is not None:
+                        req.logits.append(np_logits[slot])
+                    if len(req.tokens) >= req.max_new_tokens:
+                        self._complete(req)
+            phase.close("emit")
+            self.step_phases.append(phase.phases)
 
     # -- driver -------------------------------------------------------------
 
@@ -529,14 +642,18 @@ class Engine:
         Returns True if any work happened (admission or decode)."""
         if self.health.state == "failed":
             raise EngineError(f"engine failed: {self.health.detail}")
-        now = self.clock()
-        self._shed_and_evict(now)
-        admitted = self._admit()
-        self._probe_step_faults(self.clock())
-        if not self._active():
-            return admitted > 0
-        self._decode_step()
-        return True
+        with TraceAnnotation("engine.step") as span:
+            phase = _PhaseClock(self.clock, self._gc_timer)
+            self._shed_and_evict(self.clock())
+            admitted = self._admit()
+            self._probe_step_faults(self.clock())
+            rows = len(self._active())
+            span.set_metadata(prefills=admitted, rows=rows)
+            if not rows:
+                return admitted > 0
+            phase.close("admit")
+            self._decode_step(admitted, phase)
+            return True
 
     def has_work(self) -> bool:
         return bool(self._active()) or self.queue.peek_viable()
