@@ -166,6 +166,9 @@ def decode_step(params, cfg, cache, tokens):
         h = rms_norm(x, p["pre_norm"], cfg.norm_eps)
         out, kv = attention_decode_block(p["attn"], h, s, (c["k"], c["v"]),
                                          lengths, cfg.rope_theta)
+        bidx = jnp.arange(x.shape[0])
+        kv = (c["k"].at[bidx, lengths].set(kv[0]),
+              c["v"].at[bidx, lengths].set(kv[1]))
         x = x + out
         x = x + cross_attention_block(
             p["xattn"], rms_norm(x, p["xnorm"], cfg.norm_eps),

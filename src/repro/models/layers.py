@@ -229,9 +229,11 @@ def flash_attention(q, k, v, *, causal: bool, q_offset: int = 0,
     return out.astype(ACT_DTYPE)
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *,
+def decode_attention(q, k_cache, v_cache, lengths, k_new, v_new, *,
                      score_shard: bool = False):
-    """Single-token decode: q (B, 1, H, hd) over caches (B, S, KV, hd).
+    """Single-token decode: q (B, 1, H, hd) over the first ``lengths``
+    positions of caches (B, S, KV, hd) and the new token's own k_new,
+    v_new (B, 1, KV, hd), which are not in the cache.
 
     ``lengths``: (B,) int32 — number of valid cache entries per sequence.
     ``score_shard`` pins the (B, H, 1, S) score chain S-sharded on "model"
@@ -245,6 +247,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
     k_pos = jnp.arange(s_len)[None, None, None, :]
     bias = jnp.where(k_pos < lengths[:, None, None, None], 0.0, -1e30)
     scores = scores + bias  # additive mask: one fused add, no select chain
+    s_new = _chunk_scores(q, k_new, scale)  # (B, H, 1, 1)
 
     def pin(x):
         if not score_shard:
@@ -254,11 +257,13 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
             x, _P(None, None, None, "model"))
 
     scores = pin(scores)
-    m = pin(jnp.max(scores, axis=-1, keepdims=True))
-    p = pin(jnp.exp(scores - jax.lax.stop_gradient(m)))
-    denom = jnp.sum(p, axis=-1, keepdims=True)
-    probs = p / denom
-    return _chunk_out(probs.astype(ACT_DTYPE), v_cache, h)
+    m = jnp.maximum(pin(jnp.max(scores, axis=-1, keepdims=True)), s_new)
+    m = jax.lax.stop_gradient(m)
+    p = pin(jnp.exp(scores - m))
+    p_new = jnp.exp(s_new - m)
+    denom = jnp.sum(p, axis=-1, keepdims=True) + p_new
+    return (_chunk_out((p / denom).astype(ACT_DTYPE), v_cache, h)
+            + _chunk_out((p_new / denom).astype(ACT_DTYPE), v_new, h))
 
 
 def attention_block(p, x, s: AttnParamsShape, positions, theta, *,
@@ -276,20 +281,21 @@ def attention_decode_block(p, x, s: AttnParamsShape, cache_kv, lengths,
                            theta, score_shard: bool = False):
     """One-token decode step. x: (B, 1, D). cache_kv: (k, v) (B, S, KV, hd).
 
-    Writes the new k/v at position ``lengths`` per sequence, then attends.
+    Attends over each row's first ``lengths`` cached positions plus the new
+    token's own k/v, and leaves the cache as it is: a decode step reads the
+    cache once and writes one position per row, so it returns the new
+    ``(k, v)``, each (B, KV, hd), for the caller to store at position
+    ``lengths``.
     """
+    b = x.shape[0]
     k_cache, v_cache = cache_kv
     positions = lengths[:, None]  # (B, 1) — rope position of the new token
     q, k_new, v_new = _project_qkv(p, x, s, positions, theta)
-    b = x.shape[0]
-    bidx = jnp.arange(b)
-    k_cache = k_cache.at[bidx, lengths].set(k_new[:, 0])
-    v_cache = v_cache.at[bidx, lengths].set(v_new[:, 0])
-    out = decode_attention(q, k_cache, v_cache, lengths + 1,
+    out = decode_attention(q, k_cache, v_cache, lengths, k_new, v_new,
                            score_shard=score_shard)
     out = weight_matmul(p["wo"], out.reshape(b, 1, -1),
                         "btf,fd->btd").astype(x.dtype)
-    return out, (k_cache, v_cache)
+    return out, (k_new[:, 0], v_new[:, 0])
 
 
 # ---------------------------------------------------------------------------

@@ -154,21 +154,21 @@ def _apply_position(p, desc: BlockDesc, cfg, x, positions, *,
 
 
 def _apply_position_step(p, desc: BlockDesc, cfg, x, cache, lengths):
-    """One-token decode of one block. Returns (x, new_cache_entry, aux)."""
+    """One-token decode of one block, reading its cache entry.  Returns
+    (x, step): an attention block's new token ``{"k", "v"}`` (B, KV, hd),
+    which :func:`_write_step` stores, or a recurrent block's new state."""
     h = rms_norm(x, p["pre_norm"], cfg.norm_eps)
     if desc.seq == "attn":
         out, kv = attention_decode_block(
             p["attn"], h, attn_shape(cfg), (cache["k"], cache["v"]),
             lengths, cfg.rope_theta, score_shard=cfg.decode_score_shard)
-        new_cache = {"k": kv[0], "v": kv[1]}
+        step = {"k": kv[0], "v": kv[1]}
     elif desc.seq == "mamba":
-        out, new_cache = ssm_lib.mamba_step(p["mamba"], h, cache,
-                                            cfg.ssm_state)
+        out, step = ssm_lib.mamba_step(p["mamba"], h, cache, cfg.ssm_state)
     elif desc.seq == "mlstm":
-        out, new_cache = xlstm_lib.mlstm_step(p["mlstm"], h, cache,
-                                              cfg.n_heads)
+        out, step = xlstm_lib.mlstm_step(p["mlstm"], h, cache, cfg.n_heads)
     elif desc.seq == "slstm":
-        out, new_cache = xlstm_lib.slstm_step(p["slstm"], h, cache)
+        out, step = xlstm_lib.slstm_step(p["slstm"], h, cache)
     x = x + out
     if desc.ffn == "mlp":
         x = x + mlp_block(p["mlp"], rms_norm(x, p["post_norm"], cfg.norm_eps))
@@ -179,7 +179,37 @@ def _apply_position_step(p, desc: BlockDesc, cfg, x, cache, lengths):
                                    cfg.moe_combine_dtype,
                                    cfg.moe_dispatch_a2a)
         x = x + out
-    return x, new_cache
+    return x, step
+
+
+def _write_step(program, entries, steps, lengths):
+    """Store one decode step's per-layer outputs (stacked over periods) in
+    the cache, touching rows [0, B) only (B = ``lengths``' size).
+    Attention entries take each row's new K and V, for every layer at
+    once, at position ``lengths[row]``: one in-place
+    ``dynamic_update_slice`` per row.  (A single scatter would do the same
+    work, but XLA:TPU runs a scatter only in the default layout, so it
+    would copy the whole cache out of its compact one and back.)
+    Recurrent states are small and are replaced over those rows whole."""
+    out = []
+    for desc, entry, step in zip(program, entries, steps):
+        if desc.seq == "attn":
+            new = {}
+            for n in ("k", "v"):
+                ring = entry[n]
+                upd = step[n].astype(ring.dtype)[:, :, None]
+                for row in range(lengths.shape[0]):
+                    ring = jax.lax.dynamic_update_slice(
+                        ring, upd[:, row:row + 1],
+                        (0, row, lengths[row], 0, 0))
+                new[n] = ring
+            out.append(new)
+        else:
+            out.append(jax.tree.map(
+                lambda full, part: jax.lax.dynamic_update_slice_in_dim(
+                    full, part.astype(full.dtype), 0, axis=1),
+                entry, step))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -372,7 +402,12 @@ def prefill_fn(params, cfg, batch, max_len: int):
 
 
 def decode_fn(params, cfg, cache, tokens):
-    """One decode step. tokens: (B,) int32. Returns (logits (B, V), cache)."""
+    """One decode step. tokens: (B,) int32. Returns (logits (B, V), cache).
+
+    The cache's entries may hold more rows than the batch (a serving
+    engine's slot ring): the step reads and writes rows [0, B) only, and
+    writes each attention layer one position per row (:func:`_write_step`).
+    ``cache["lengths"]`` is (B,)."""
     program = block_program(cfg)
     n_periods = cfg.n_layers // len(program)
     embed = _dense_leaf(params["embed"])
@@ -386,13 +421,14 @@ def decode_fn(params, cfg, cache, tokens):
         return serve_logits(x, head)[:, 0], dict(cache, lengths=lengths + 1)
 
     def period_body(x, sliced_params, sliced_cache):
-        new_entries = []
+        steps = []
         for pos, desc in enumerate(program):
             p = resolve_weights(sliced_params[pos])
-            x, new_c = _apply_position_step(p, desc, cfg, x,
-                                            sliced_cache[pos], lengths)
-            new_entries.append(new_c)
-        return x, new_entries
+            rows = jax.tree.map(lambda a: a[:tokens.shape[0]],
+                                sliced_cache[pos])
+            x, step = _apply_position_step(p, desc, cfg, x, rows, lengths)
+            steps.append(step)
+        return x, steps
 
     if overlap_enabled(getattr(cfg, "overlap", "auto"), period):
         schedule = build_schedule(period, n_periods)
@@ -401,30 +437,27 @@ def decode_fn(params, cfg, cache, tokens):
             return period_body(x, sliced, sliced_cache)
 
         if cfg.scan_layers:
-            x, new_entries = pipeline_scan(schedule, apply_fn, x,
-                                           xs_extra=entries)
+            x, steps = pipeline_scan(schedule, apply_fn, x, xs_extra=entries)
         else:
             x, outs = pipeline_unrolled(schedule, apply_fn, x,
                                         xs_extra=entries)
-            new_entries = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        cache = {"entries": new_entries, "lengths": lengths + 1}
+            steps = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
     elif cfg.scan_layers:
         def scan_body(x, sl):
             sp, sc = sl
-            x, new_entries = period_body(x, sp, sc)
-            return x, new_entries
+            return period_body(x, sp, sc)
 
-        x, new_entries = jax.lax.scan(scan_body, x, (period, entries))
-        cache = {"entries": new_entries, "lengths": lengths + 1}
+        x, steps = jax.lax.scan(scan_body, x, (period, entries))
     else:
         outs = []
         for i in range(n_periods):
             sp = jax.tree.map(lambda a: a[i], period)
             sc = jax.tree.map(lambda a: a[i], entries)
-            x, new_e = period_body(x, sp, sc)
-            outs.append(new_e)
-        new_entries = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        cache = {"entries": new_entries, "lengths": lengths + 1}
+            x, step = period_body(x, sp, sc)
+            outs.append(step)
+        steps = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    cache = {"entries": _write_step(program, entries, steps, lengths),
+             "lengths": lengths + 1}
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = embed.T if cfg.tie_embeddings else _dense_leaf(params["head"])
     return serve_logits(x, head)[:, 0], cache

@@ -339,31 +339,29 @@ class Engine:
                         full, part, slot, axis=1),
                     entries, req_entries)
 
-            self._install_fn = jax.jit(engine_install)
+            # donated off the CPU, as the step is: the slot is written in place
+            donate = (0,) if jax.default_backend() != "cpu" else ()
+            self._install_fn = jax.jit(engine_install, donate_argnums=donate)
         self._entries = self._install_fn(self._entries, req_entries,
                                          np.int32(slot))
 
     def _step_fn(self, bucket: int):
-        """One fused decode step over slots ``[0, bucket)``: slice the
-        ring, decode, argmax, scatter the updated cache back."""
+        """One fused decode step over slots ``[0, bucket)``: decode and
+        argmax.  The model gets the whole ring and a batch of ``bucket``
+        rows; it reads those rows and writes each attention layer's new
+        token into them in place, so the donated ring is never sliced or
+        copied."""
         if bucket not in self._step_fns:
             import jax
             import jax.numpy as jnp
             model = self.model
 
             def engine_decode_step(params, entries, tokens, lengths):
-                sub = jax.tree.map(
-                    lambda a: jax.lax.slice_in_dim(a, 0, bucket, axis=1),
-                    entries)
-                cache = {"entries": sub, "lengths": lengths[:bucket]}
+                cache = {"entries": entries, "lengths": lengths[:bucket]}
                 logits, new_cache = model.decode_fn(params, cache,
                                                     tokens[:bucket])
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
-                new_entries = jax.tree.map(
-                    lambda full, part: jax.lax.dynamic_update_slice_in_dim(
-                        full, part, 0, axis=1),
-                    entries, new_cache["entries"])
-                return tok, logits, new_entries
+                return tok, logits, new_cache["entries"]
 
             donate = (1,) if jax.default_backend() != "cpu" else ()
             self._step_fns[bucket] = jax.jit(engine_decode_step,

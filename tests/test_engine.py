@@ -12,6 +12,7 @@ new; the overload governor sheds queued low-priority work and degrades
 admission, never admitted-request latency.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.models import build_model
+from repro.models import build_model, lm
 from repro.runtime import faults as rt_faults
 from repro.runtime.admission import (AdmissionQueue, OverloadGovernor,
                                      Request)
@@ -138,6 +139,83 @@ def test_bucket_compiles_are_bounded(setup):
     engine.run_until_idle()
     buckets = engine.stats()["engine"]["compiled_buckets"]
     assert set(buckets) <= {1, 2, 4} and len(buckets) <= 3
+
+
+# ---------------------------------------------------------------------------
+# the KV ring is written in place (tests/test_engine_drivers.py: every layer
+# driver serves the tokens of a full-sequence forward)
+# ---------------------------------------------------------------------------
+
+_TENSOR = re.compile(r"tensor<((?:\d+x)+)[a-z]\w*>")
+_WRITE = re.compile(r"(%[\w#]+) = (stablehlo\.dynamic_update_slice|"
+                    r"\"stablehlo\.scatter\")[(\s]+(%[\w#]+)")
+
+
+def _shape(tensor_type: str) -> tuple:
+    return tuple(int(d) for d in tensor_type.rstrip("x").split("x"))
+
+
+def _cache_writes(hlo: str, cache_tail: tuple) -> list:
+    """Every op of the lowered step that writes a tensor (the keyed ops:
+    ``stablehlo.dynamic_update_slice`` and ``stablehlo.scatter``) and
+    yields one shaped like a K/V cache (ending in max_len, KV, hd), as
+    ``(name, op, target, operand shapes, result shape)``."""
+    lines = hlo.splitlines()
+    out = []
+    for i, line in enumerate(lines):
+        m = _WRITE.search(line)
+        if m is None:
+            continue
+        sig = line
+        while "->" not in sig:          # a scatter's types close its region
+            i += 1
+            sig = lines[i]
+        operands, result = sig.rsplit("->", 1)
+        types = operands.split(" : ")[-1]
+        shapes = [_shape(t) for t in _TENSOR.findall(types)]
+        res = _shape(_TENSOR.findall(result)[0])
+        if res[-3:] == cache_tail:
+            out.append((m.group(1), m.group(2).strip('"'), m.group(3),
+                        shapes, res))
+    return out
+
+
+@pytest.mark.parametrize("bucket", [4, 2])
+def test_decode_step_writes_one_position_per_row_in_place(setup, bucket):
+    """The lowered decode step writes the ring only by
+    ``stablehlo.dynamic_update_slice``s of one position per row, chained
+    from the ring argument to the returned ring: no layer computes, and no
+    layer loop stacks, a whole layer's K/V cache (a per-layer
+    ``stablehlo.scatter`` of the new token, or a whole-layer or whole-ring
+    ``dynamic_update_slice``, would), and the step never slices the ring
+    and writes it back.  Donated on the chip, the returned ring is the
+    input's buffer."""
+    cfg, model, params, _ = setup
+    engine = Engine(model, params, _ecfg(max_slots=4))
+    s = lm.attn_shape(cfg)
+    max_len = engine.config.max_len
+    ring = (cfg.n_layers, 4, max_len, s.n_kv_heads, s.head_dim)
+    hlo = engine.step_hlo(bucket)
+    writes = _cache_writes(hlo, ring[-3:])
+    assert writes
+    for name, op, _, shapes, res in writes:
+        assert op == "stablehlo.dynamic_update_slice", (name, op, res)
+        assert res == ring, (name, res)
+        assert shapes[1] == (cfg.n_layers, 1, 1) + ring[-2:], (name, shapes)
+    # the rows written are the bucket's, each once per K and V
+    assert len(writes) == 2 * bucket
+    main = hlo[hlo.index("@main("):]
+    main = main[:main.index("\n  }")]
+    ret = main[main.rindex("return "):].split(":")[0].split()[1:]
+    source = {name: target for name, _, target, _, _ in writes}
+    ring_args = re.findall(r"(%arg\d+): tensor<" + "x".join(map(str, ring)),
+                           main)
+    returned = [v.rstrip(",") for v in ret if v.rstrip(",") in source]
+    assert len(returned) == len(ring_args) == 2
+    for value in returned:
+        while value in source:
+            value = source[value]
+        assert value in ring_args
 
 
 # ---------------------------------------------------------------------------
